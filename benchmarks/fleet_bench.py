@@ -31,7 +31,7 @@ Run:  PYTHONPATH=src python benchmarks/fleet_bench.py [--smoke] [--no-table1]
 
 The script forces its own ``--xla_force_host_platform_device_count``
 (before first jax init) when launched as __main__; through
-``benchmarks/run.py`` it runs in a subprocess for the same reason.
+``benchmarks/run.py`` it runs in that process, on the devices it sees.
 """
 from __future__ import annotations
 

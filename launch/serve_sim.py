@@ -18,6 +18,7 @@ import time
 import jax
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import module as nnm
 from repro.nn.agent_sim import AgentSimConfig, AgentSimModel
 from repro.runtime.sim_server import SceneRequest, SimServer, poisson_drive
@@ -80,6 +81,7 @@ def main():
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(message)s")
     log = logging.getLogger("serve_sim")
+    log.info("compilation cache: %s", enable_compile_cache())
 
     reg = obs.Registry()
     scen, model, params = build(args)

@@ -20,7 +20,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.optim.transforms import apply_updates
@@ -89,8 +88,8 @@ def make_compressed_dp_step(loss_fn: Callable, optimizer, mesh: Mesh,
         return params, opt_state, residual, loss
 
     dp_axes = (pod_axis, data_axis) if have_pod else (data_axis,)
-    return shard_map(
+    return jax.shard_map(
         shard_step, mesh=mesh,
         in_specs=(P(), P(), P(), P(dp_axes)),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)

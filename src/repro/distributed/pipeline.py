@@ -25,7 +25,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -99,12 +98,12 @@ def make_pipelined_fn(block_fn: Callable, mesh: Mesh, cfg: PipelineConfig,
         xm = x.reshape((m, x.shape[0] // m) + x.shape[1:])
         inner = functools.partial(pipeline_apply, block_fn, cfg=cfg,
                                   axis_name=axis_name)
-        out = shard_map(
+        out = jax.shard_map(
             lambda sp, xi: inner(sp, xi),
             mesh=mesh,
             in_specs=(P(axis_name), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(params, xm)
         return out.reshape(x.shape[:1] + out.shape[2:])
 

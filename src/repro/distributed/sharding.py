@@ -82,6 +82,19 @@ def use_mesh_rules(mesh: Mesh, rules: Optional[Dict[str, Tuple[str, ...]]] = Non
         _CTX.mesh, _CTX.rules = prev
 
 
+@contextlib.contextmanager
+def without_mesh_rules():
+    """Turn logical constraints off, e.g. while tracing a ``shard_map``
+    body, whose per-device code an enclosing mesh's rules do not describe.
+    Also a decorator: ``without_mesh_rules()(fn)``."""
+    prev = (_CTX.mesh, _CTX.rules)
+    _CTX.mesh, _CTX.rules = None, None
+    try:
+        yield
+    finally:
+        _CTX.mesh, _CTX.rules = prev
+
+
 def active_mesh() -> Optional[Mesh]:
     return _CTX.mesh
 
@@ -143,6 +156,31 @@ def logical_constraint(x, *logical_axes: Optional[str]):
         return x
     spec = spec_for(x.shape, logical_axes, mesh, rules)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def per_batch_head_shard(fn, q, k, v, *rows):
+    """``fn(q, k, v, *rows)`` on each device's block of the active mesh.
+
+    Attention is independent per (batch, head), so under a mesh it runs as
+    a ``shard_map``: q/k/v ``(B, H, S, d)`` split batch over the DP axes and
+    heads over the model axis, per-token ``rows`` ``(B, S)`` split like the
+    batch. The compiler cannot partition a Pallas kernel itself (a Mosaic
+    custom call). Without an active mesh this is ``fn(q, k, v, *rows)``.
+    """
+    mesh, rules = _CTX.mesh, _CTX.rules
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v, *rows)
+    axes = ("act_batch", "act_heads", None, None)
+    q_spec, k_spec = spec_for(q.shape, axes, mesh, rules), \
+        spec_for(k.shape, axes, mesh, rules)
+    pad = lambda spec: tuple(spec) + (None,) * (2 - len(spec))
+    (qb, qh), (kb, kh) = pad(q_spec)[:2], pad(k_spec)[:2]
+    if qh != kh:      # grouped kv heads that do not split like q's heads
+        qh = kh = None
+    q_spec, k_spec, row = P(qb, qh), P(kb, kh), P(qb)
+    return jax.shard_map(without_mesh_rules()(fn), mesh=mesh,
+                         in_specs=(q_spec, k_spec, k_spec) + (row,) * len(rows),
+                         out_specs=q_spec, check_vma=False)(q, k, v, *rows)
 
 
 def sharding_for_specs(spec_tree, mesh: Mesh,

@@ -36,9 +36,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 _NEG_INF = -1e30
+
+
+def row_operands(q_vec, k_vec):
+    """Lay out a pair of per-token (B, S) vectors for Mosaic's tiling.
+
+    A block's last two dims must either equal the array's or be divisible
+    by (8, 128), so a ``(1, block)`` tile of a ``(B, S)`` array is refused
+    whenever B > 1. Query-side vectors become ``(B, Sq, 1)`` columns and
+    key-side vectors ``(B, 1, Sk)`` rows: their ``(block_q, 1)`` /
+    ``(1, block_k)`` tiles are legal, and inside the kernel they arrive
+    already oriented for the ``(block_q, block_k)`` mask broadcast.
+    """
+    return q_vec[:, :, None], k_vec[:, None, :]
 
 
 def _fwd_kernel(q_seg_ref, k_seg_ref, q_time_ref, k_time_ref,
@@ -83,8 +94,8 @@ def _fwd_kernel(q_seg_ref, k_seg_ref, q_time_ref, k_time_ref,
             s = jnp.tanh(s / softcap) * softcap
 
         if use_times:
-            rows = q_time_ref[0][:, None]            # (bq, 1)
-            cols = k_time_ref[0][None, :]            # (1, bk)
+            rows = q_time_ref[0]                     # (bq, 1)
+            cols = k_time_ref[0]                     # (1, bk)
         else:
             rows = jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0) + q_start
@@ -96,9 +107,9 @@ def _fwd_kernel(q_seg_ref, k_seg_ref, q_time_ref, k_time_ref,
         if window is not None:
             mask = jnp.logical_and(mask, cols > rows - window)
         if use_segments:
-            qs = q_seg_ref[0]                         # (bq,)
-            ks = k_seg_ref[0]                         # (bk,)
-            seg = jnp.logical_and(qs[:, None] == ks[None, :], ks[None, :] >= 0)
+            qs = q_seg_ref[0]                         # (bq, 1)
+            ks = k_seg_ref[0]                         # (1, bk)
+            seg = jnp.logical_and(qs == ks, ks >= 0)
             mask = jnp.logical_and(mask, seg)
         s = jnp.where(mask, s, _NEG_INF)
 
@@ -122,9 +133,9 @@ def _fwd_kernel(q_seg_ref, k_seg_ref, q_time_ref, k_time_ref,
 
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = m_ref[:, 0] + jnp.log(l)
+        l = jnp.maximum(l_ref[:, :1], 1e-30)          # (bq, 1)
+        o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
 
 
 def flash_attention_fwd(q, k, v, *,
@@ -162,19 +173,21 @@ def flash_attention_fwd(q, k, v, *,
         q_times = jnp.zeros((b, sq), jnp.int32)
         k_times = jnp.zeros((b, sk), jnp.int32)
 
+    q_segment_ids, k_segment_ids = row_operands(q_segment_ids, k_segment_ids)
+    q_times, k_times = row_operands(q_times, k_times)
+
     kernel = functools.partial(
         _fwd_kernel, scale=float(scale), causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k, num_k_blocks=nk,
         use_segments=use_segments, use_times=use_times)
 
+    q_row = pl.BlockSpec((1, block_q, 1), lambda b_, h, iq, ik: (b_, iq, 0))
+    k_row = pl.BlockSpec((1, 1, block_k), lambda b_, h, iq, ik: (b_, 0, ik))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b_, h, iq, ik: (b_, iq)),
-            pl.BlockSpec((1, block_k), lambda b_, h, iq, ik: (b_, ik)),
-            pl.BlockSpec((1, block_q), lambda b_, h, iq, ik: (b_, iq)),
-            pl.BlockSpec((1, block_k), lambda b_, h, iq, ik: (b_, ik)),
+            q_row, k_row, q_row, k_row,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
             pl.BlockSpec((1, 1, block_k, d),
@@ -185,21 +198,21 @@ def flash_attention_fwd(q, k, v, *,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, dv),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h, iq, ik: (b_, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h, iq, ik: (b_, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, dv), v.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),    # acc
             pltpu.VMEM((block_q, 128), jnp.float32),   # m (running max)
             pltpu.VMEM((block_q, 128), jnp.float32),   # l (running denom)
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q_segment_ids, k_segment_ids, q_times, k_times, q, k, v)
-    return (out, lse) if return_lse else out
+    return (out, lse[..., 0]) if return_lse else out

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.flash_attention import row_operands
 
 _NEG_INF = -1e30
 
@@ -56,7 +56,8 @@ def _block_probs_and_ds(q, k, v, do, lse, delta, *, scale, softcap,
     """Shared recompute: P from saved LSE, then dS (pre-softmax grad).
 
     All operands are float32 tiles: q (bq, d), k (bk, d), v (bk, dv),
-    do (bq, dv), lse/delta (bq,). Returns (p, ds) both (bq, bk), with dS
+    do (bq, dv), lse/delta (bq, 1) columns; the segment ids arrive as a
+    (bq, 1) column and a (1, bk) row. Returns (p, ds) both (bq, bk), with dS
     already including the softcap chain rule and the score scale.
     """
     s_pre = jax.lax.dot_general(
@@ -76,18 +77,17 @@ def _block_probs_and_ds(q, k, v, do, lse, delta, *, scale, softcap,
     if window is not None:
         mask = jnp.logical_and(mask, cols > rows - window)
     if use_segments:
-        seg = jnp.logical_and(q_seg[:, None] == k_seg[None, :],
-                              k_seg[None, :] >= 0)
+        seg = jnp.logical_and(q_seg == k_seg, k_seg >= 0)
         mask = jnp.logical_and(mask, seg)
 
     # P = exp(S - lse) is exactly softmax(S) restricted to this block; rows
     # that were fully masked in the forward carry lse = log(1e-30) and are
     # masked to zero here anyway.
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if dcap is not None:
         ds = ds * dcap
     ds = ds * scale
@@ -97,8 +97,8 @@ def _block_probs_and_ds(q, k, v, do, lse, delta, *, scale, softcap,
 def _mask_geometry(q_time_ref, k_time_ref, q_start, k_start, *,
                    block_q, block_k, use_times):
     if use_times:
-        rows = q_time_ref[0][:, None]                    # (bq, 1)
-        cols = k_time_ref[0][None, :]                    # (1, bk)
+        rows = q_time_ref[0]                             # (bq, 1)
+        cols = k_time_ref[0]                             # (1, bk)
     else:
         rows = jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0) + q_start
@@ -272,22 +272,27 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
         k_times = jnp.zeros((b, sk), jnp.int32)
 
     # FlashAttention-2 preprocess: delta_i = sum_j dO_ij O_ij, an O(Sq)
-    # elementwise reduce that XLA fuses well; not worth a kernel.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse = lse.astype(jnp.float32)
+    # elementwise reduce that XLA fuses well; not worth a kernel. Both row
+    # terms travel as (B, Hq, Sq, 1) so their (block_q, 1) tiles are legal.
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    lse = lse.astype(jnp.float32)[..., None]
+    q_segment_ids, k_segment_ids = row_operands(q_segment_ids, k_segment_ids)
+    q_times, k_times = row_operands(q_times, k_times)
 
     common = dict(scale=float(scale), causal=causal, window=window,
                   softcap=softcap, block_q=block_q, block_k=block_k,
                   use_segments=use_segments, use_times=use_times)
 
+    q_row = pl.BlockSpec((1, block_q, 1), lambda b_, h, iq, ik: (b_, iq, 0))
+    k_row = pl.BlockSpec((1, 1, block_k), lambda b_, h, iq, ik: (b_, 0, ik))
+    q_col = pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h, iq, ik: (b_, h, iq, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, num_k_blocks=nk, **common),
         grid=(b, hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b_, h, iq, ik: (b_, iq)),
-            pl.BlockSpec((1, block_k), lambda b_, h, iq, ik: (b_, ik)),
-            pl.BlockSpec((1, block_q), lambda b_, h, iq, ik: (b_, iq)),
-            pl.BlockSpec((1, block_k), lambda b_, h, iq, ik: (b_, ik)),
+            q_row, k_row, q_row, k_row,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
             pl.BlockSpec((1, 1, block_k, d),
@@ -296,14 +301,13 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
                          lambda b_, h, iq, ik: (b_, h // group, ik, 0)),
             pl.BlockSpec((1, 1, block_q, dv),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik: (b_, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik: (b_, h, iq)),
+            q_col, q_col,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda b_, h, iq, ik: (b_, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -317,27 +321,25 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
     def _qh(h, iqg):
         return h * group + iqg // nq
 
+    q_row = pl.BlockSpec((1, block_q, 1),
+                         lambda b_, h, ik, iqg: (b_, iqg % nq, 0))
+    k_row = pl.BlockSpec((1, 1, block_k), lambda b_, h, ik, iqg: (b_, 0, ik))
+    q_col = pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h, ik, iqg: (b_, _qh(h, iqg), iqg % nq, 0))
+
     dk, dv_out = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q_blocks=nq, num_inner=num_inner,
                           **common),
         grid=(b, hkv, nk, num_inner),
         in_specs=[
-            pl.BlockSpec((1, block_q),
-                         lambda b_, h, ik, iqg: (b_, iqg % nq)),
-            pl.BlockSpec((1, block_k), lambda b_, h, ik, iqg: (b_, ik)),
-            pl.BlockSpec((1, block_q),
-                         lambda b_, h, ik, iqg: (b_, iqg % nq)),
-            pl.BlockSpec((1, block_k), lambda b_, h, ik, iqg: (b_, ik)),
+            q_row, k_row, q_row, k_row,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h, ik, iqg: (b_, _qh(h, iqg),
                                                  iqg % nq, 0)),
             pl.BlockSpec((1, 1, block_q, dv),
                          lambda b_, h, ik, iqg: (b_, _qh(h, iqg),
                                                  iqg % nq, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h, ik, iqg: (b_, _qh(h, iqg), iqg % nq)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h, ik, iqg: (b_, _qh(h, iqg), iqg % nq)),
+            q_col, q_col,
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h, ik, iqg: (b_, h, ik, 0)),
             pl.BlockSpec((1, 1, block_k, dv),
@@ -357,7 +359,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             pltpu.VMEM((block_k, d), jnp.float32),     # dk accumulator
             pltpu.VMEM((block_k, dv), jnp.float32),    # dv accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -62,7 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.flash_attention import row_operands
 
 _NEG_INF = -1e30
 
@@ -112,8 +112,14 @@ def dequantize_kv(q, scale, dtype=jnp.float32):
 # The split-K kernel.
 # ---------------------------------------------------------------------------
 
+def _column(row):
+    """(1, n) lane row -> (n, 1) sublane column, bit-exact: an 8-sublane
+    broadcast and a 2D transpose, both native to Mosaic."""
+    return jnp.transpose(jnp.broadcast_to(row, (8, row.shape[1])))[:, :1]
+
+
 def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
-                   blocks_per_split: int, num_k_blocks: int,
+                   blocks_per_split: int, num_k_blocks: int, group: int,
                    use_segments: bool, use_times: bool,
                    quant_k: bool, quant_v: bool, layered: bool):
     """One grid step: fold one key block into this split's (m, l, acc).
@@ -137,6 +143,7 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
     acc_s, m_s, l_s = refs[i + 3:]
 
     b = pl.program_id(0)
+    hk = pl.ds(pl.program_id(1) // group, 1)     # this program's kv head
     split = pl.program_id(2)
     ik = pl.program_id(3)
     jk = split * blocks_per_split + ik          # global key-block index
@@ -156,15 +163,21 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
 
     kv_idx = (0, 0, 0) if layered else (0, 0)    # layer-stacked cache tiles
 
+    def row_scale(ref):
+        # the scale tile spans every kv head (a one-head (1, block_k) tile
+        # is not a legal block); pick this program's head, as a column
+        row = ref[0, 0, hk, :] if layered else ref[0, hk, :]
+        return _column(row)                          # (bk, 1)
+
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (bq, d)
         k = k_ref[kv_idx].astype(jnp.float32)        # (bk, d)
         v = v_ref[kv_idx].astype(jnp.float32)        # (bk, dv)
         if quant_k:
-            k = k * k_scale_ref[kv_idx][:, None]
+            k = k * row_scale(k_scale_ref)
         if quant_v:
-            v = v * v_scale_ref[kv_idx][:, None]
+            v = v * row_scale(v_scale_ref)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -173,21 +186,21 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
             jnp.int32, s.shape, 1) + k_start
         mask = cols < kvl                            # ragged cursor bound
         if use_times:
-            rows_t = q_time_ref[0][:, None]          # (bq, 1)
-            cols_t = k_time_ref[0][None, :]          # (1, bk)
+            rows_t = q_time_ref[0]                   # (bq, 1)
+            cols_t = k_time_ref[0]                   # (1, bk)
             mask = jnp.logical_and(mask, cols_t <= rows_t)
         if use_segments:
-            qs = q_seg_ref[0]
-            ks = k_seg_ref[0]
-            seg = jnp.logical_and(qs[:, None] == ks[None, :],
-                                  ks[None, :] >= 0)
+            qs = q_seg_ref[0]                        # (bq, 1)
+            ks = k_seg_ref[0]                        # (1, bk)
+            seg = jnp.logical_and(qs == ks, ks >= 0)
             mask = jnp.logical_and(mask, seg)
         s = jnp.where(mask, s, _NEG_INF)
         # zero unreachable rows' VALUES too, not just their weights:
         # 0 * NaN is NaN, and rows beyond the cursor may carry any bit
         # pattern (a quarantined predecessor's NaN rows included). For
         # finite stale rows this is an exact no-op (0 * finite == 0).
-        v = jnp.where(jnp.any(mask, axis=0)[:, None], v, 0.0)
+        reachable = jnp.any(mask, axis=0, keepdims=True)
+        v = jnp.where(_column(reachable.astype(jnp.float32)) > 0, v, 0.0)
 
         m_prev = m_s[:, 0]
         l_prev = l_s[:, 0]
@@ -205,8 +218,8 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
     @pl.when(ik == blocks_per_split - 1)
     def _finalize():
         o_ref[0, 0, 0] = acc_s[...]
-        m_ref[0, 0, 0] = m_s[:, 0]
-        l_ref[0, 0, 0] = l_s[:, 0]
+        m_ref[0, 0, 0] = m_s[:, :1]
+        l_ref[0, 0, 0] = l_s[:, :1]
 
 
 def _combine_splits(o_p, m_p, l_p, out_dtype):
@@ -214,17 +227,17 @@ def _combine_splits(o_p, m_p, l_p, out_dtype):
     reduction): rescale every split to the global row max, sum the
     denominators and accumulators, normalize once.
 
-    o_p (B, H, S, bq, dv); m_p / l_p (B, H, S, bq), all float32. A split
+    o_p (B, H, S, bq, dv); m_p / l_p (B, H, S, bq, 1), all float32. A split
     that saw only dead blocks contributes m = -1e30 (finite sentinel, so
     exp stays NaN-free), l = 0, acc = 0 — an exact no-op in the sums.
     Rows with no live key anywhere end with l == 0 and are forced to
     zero, matching ``ref.mha_reference``'s fully-masked-row convention.
     """
-    m_g = jnp.max(m_p, axis=2)                           # (B, H, bq)
-    alpha = jnp.exp(m_p - m_g[:, :, None])               # (B, H, S, bq)
+    m_g = jnp.max(m_p, axis=2)                           # (B, H, bq, 1)
+    alpha = jnp.exp(m_p - m_g[:, :, None])               # (B, H, S, bq, 1)
     l_g = jnp.sum(l_p * alpha, axis=2)
-    o = jnp.sum(o_p * alpha[..., None], axis=2)
-    out = o / jnp.maximum(l_g, 1e-30)[..., None]
+    o = jnp.sum(o_p * alpha, axis=2)
+    out = o / jnp.maximum(l_g, 1e-30)
     return out.astype(out_dtype)
 
 
@@ -293,14 +306,16 @@ def flash_decode_fwd(q, k, v, kv_length, *,
         hi = jnp.maximum(jnp.minimum(nlive, nk) - 1, 0)
         return jnp.minimum(jk, hi)
 
+    # Scale tiles span all kv heads: (1, block_k) of one head is not a
+    # legal block unless Hkv == 1, (Hkv, block_k) always is.
     if layer is None:
         def kv_map(b_, h, s, ik, kvl_ref):
             return (b_, h // group, _clamped(s * bps + ik, kvl_ref[b_]), 0)
 
         def kvec_map(b_, h, s, ik, kvl_ref):
-            return (b_, h // group, _clamped(s * bps + ik, kvl_ref[b_]))
+            return (b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
 
-        kv_block = (1, 1, block_k)
+        kv_block = (1, hkv, block_k)
         kd_block = (1, 1, block_k, d)
         kdv_block = (1, 1, block_k, dv)
     else:
@@ -309,21 +324,21 @@ def flash_decode_fwd(q, k, v, kv_length, *,
                     _clamped(s * bps + ik, kvl_ref[b_]), 0)
 
         def kvec_map(b_, h, s, ik, kvl_ref):
-            return (layer, b_, h // group,
-                    _clamped(s * bps + ik, kvl_ref[b_]))
+            return (layer, b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
 
-        kv_block = (1, 1, 1, block_k)
+        kv_block = (1, 1, hkv, block_k)
         kd_block = (1, 1, 1, block_k, d)
         kdv_block = (1, 1, 1, block_k, dv)
 
     def krow_map(b_, h, s, ik, kvl_ref):
-        return (b_, _clamped(s * bps + ik, kvl_ref[b_]))
+        return (b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
 
+    q_segment_ids, k_segment_ids = row_operands(q_segment_ids, k_segment_ids)
+    q_times, k_times = row_operands(q_times, k_times)
+    q_row = pl.BlockSpec((1, sq, 1), lambda b_, h, s, ik, kvl_ref: (b_, 0, 0))
+    k_row = pl.BlockSpec((1, 1, block_k), krow_map)
     in_specs = [
-        pl.BlockSpec((1, sq), lambda b_, h, s, ik, kvl_ref: (b_, 0)),
-        pl.BlockSpec((1, block_k), krow_map),
-        pl.BlockSpec((1, sq), lambda b_, h, s, ik, kvl_ref: (b_, 0)),
-        pl.BlockSpec((1, block_k), krow_map),
+        q_row, k_row, q_row, k_row,
         pl.BlockSpec((1, 1, sq, d),
                      lambda b_, h, s, ik, kvl_ref: (b_, h, 0, 0)),
         pl.BlockSpec(kd_block, kv_map),
@@ -339,7 +354,7 @@ def flash_decode_fwd(q, k, v, kv_length, *,
 
     kernel = functools.partial(
         _decode_kernel, scale=float(scale), block_k=block_k,
-        blocks_per_split=bps, num_k_blocks=nk,
+        blocks_per_split=bps, num_k_blocks=nk, group=group,
         use_segments=use_segments, use_times=use_times,
         quant_k=quant_k, quant_v=quant_v, layered=layer is not None)
 
@@ -350,10 +365,10 @@ def flash_decode_fwd(q, k, v, kv_length, *,
         out_specs=[
             pl.BlockSpec((1, 1, 1, sq, dv),
                          lambda b_, h, s, ik, kvl_ref: (b_, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, sq),
-                         lambda b_, h, s, ik, kvl_ref: (b_, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, sq),
-                         lambda b_, h, s, ik, kvl_ref: (b_, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, sq, 1),
+                         lambda b_, h, s, ik, kvl_ref: (b_, h, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, sq, 1),
+                         lambda b_, h, s, ik, kvl_ref: (b_, h, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((sq, dv), jnp.float32),     # acc
@@ -366,10 +381,10 @@ def flash_decode_fwd(q, k, v, kv_length, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, num_splits, sq, dv), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, num_splits, sq), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, num_splits, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, num_splits, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, num_splits, sq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -32,8 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-from repro.kernels.pallas_compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fourier
 from repro.core.encodings import SE2Fourier, _log_spaced
@@ -159,7 +158,7 @@ def se2_fourier_project(x, pose, enc: SE2Fourier, mode: str, *,
             ],
             out_specs=pl.BlockSpec((block_t, c), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((tp, c), x.dtype),
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
         )(pose32, x, const_nodes, proj)
@@ -179,7 +178,7 @@ def se2_fourier_project(x, pose, enc: SE2Fourier, mode: str, *,
             ],
             out_specs=pl.BlockSpec((block_t, c), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((tp, c), x.dtype),
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
         )(pose32, x, basis_const)

@@ -11,19 +11,27 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: sharding is propagated by the compiler from the logical-axis
+    # rules (``distributed.sharding``); jax.make_mesh defaults to Explicit
+    # axes, under which a contraction over a sharded dim is a type error.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_for(num_devices: Optional[int] = None, model_axis: int = None):
     """Small-scale mesh for tests/examples on host platforms."""
     n = num_devices or len(jax.devices())
     m = model_axis or (2 if n % 2 == 0 and n > 1 else 1)
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return _auto_mesh((n // m, m), ("data", "model"))
 
 
 def make_fleet_mesh(num_devices: Optional[int] = None, *, pods: int = 1):

@@ -37,7 +37,9 @@ from repro.configs import SIM_ARCH_NAMES, get_sim_arch
 from repro.data.pipeline import ShardedIterator
 from repro.distributed.sharding import (derive_opt_shardings,
                                         sharding_for_specs, use_mesh_rules)
-from repro.launch.mesh import make_mesh_for, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import (make_fleet_mesh, make_mesh_for,
+                               make_production_mesh)
 from repro.nn import module as nnm
 from repro.nn.agent_sim import AgentSimModel
 from repro.runtime.evaluation import EvalConfig, evaluate_scenes
@@ -79,7 +81,10 @@ def make_eval_cb(model, scen, *, holdout, n_scenes_per_family: int,
 
     Scenes, the rollout engine, and the jitted open-loop eval step are all
     built once and reused — only ``engine.params`` is swapped per call, so
-    every eval after the first runs without recompilation.
+    every eval after the first runs without recompilation. With more than
+    one device the engine shards its scene lanes over all of them
+    (``make_fleet_mesh``), taking the training-sharded parameters as they
+    come.
     """
     from repro.runtime.rollout import RolloutEngine
 
@@ -96,7 +101,8 @@ def make_eval_cb(model, scen, *, holdout, n_scenes_per_family: int,
         if state["engine"] is None:
             state["engine"] = RolloutEngine(
                 model, params, scen,
-                num_slots=min(32, len(scenes) * eval_cfg.n_samples))
+                num_slots=min(32, len(scenes) * eval_cfg.n_samples),
+                mesh=make_fleet_mesh() if jax.device_count() > 1 else None)
         state["engine"].params = params
         closed = evaluate_scenes(state["engine"], scenes, eval_cfg)
         open_m = open_loop_metrics(model, params, holdout, eval_fn=eval_fn)
@@ -328,6 +334,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    log.info("compilation cache: %s", enable_compile_cache())
     if args.smoke and args.steps == 200:
         args.steps = 40
     # one fresh registry as the process default: the Trainer, every

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.encodings import GroupEncoding, make_encoding
+from repro.distributed.sharding import per_batch_head_shard
 from repro.kernels import ops as kops
 from repro.kernels.flash_decode import canonical_cache_dtype, quantize_kv
 from repro.nn.attention import _merge_heads, _split_heads
@@ -54,11 +55,15 @@ class AgentSimConfig:
     min_scale: float = 0.25
     max_scale: float = 1.0
     pos_scale: float = 0.05       # world meters -> encoder units (<= 4)
-    attn_impl: str = "ref"        # scenes are small; ref is fine on CPU
+    #: full-sequence attention impl (``kops.attention`` names). "auto" runs
+    #: the Pallas flash kernels on TPU and the chunked XLA path elsewhere;
+    #: "ref" is the O(S^2) oracle that tests pin.
+    attn_impl: str = "auto"
     #: attention impl for the cached decode path (``kops.decode_attention``
     #: names: "auto" / "flash_decode" / "xla" / "ref" / "chunked").
-    #: None falls back to ``attn_impl`` — the pre-decode-kernel behavior,
-    #: which scans the whole preallocated cache and is kept as the oracle.
+    #: None falls back to ``attn_impl``: "auto" resolves to the split-K
+    #: ragged kernel on TPU, while a pinned "ref" scans the whole
+    #: preallocated cache and is kept as the oracle.
     decode_impl: Optional[str] = None
     dtype: str = "float32"
 
@@ -217,11 +222,15 @@ class SimAttention:
         cfg = self.cfg
         q, k, v = self._qkv(params, x, pose)
         scale = 1.0 / float(cfg.head_dim) ** 0.5
-        out = kops.attention(q, k, v, impl=cfg.attn_impl, scale=scale,
-                             causal=True,
-                             q_times=times, k_times=times,
-                             q_segment_ids=segment_ids,
-                             k_segment_ids=segment_ids)
+
+        def attend(q, k, v, times, segment_ids):
+            return kops.attention(q, k, v, impl=cfg.attn_impl, scale=scale,
+                                  causal=True,
+                                  q_times=times, k_times=times,
+                                  q_segment_ids=segment_ids,
+                                  k_segment_ids=segment_ids)
+
+        out = per_batch_head_shard(attend, q, k, v, times, segment_ids)
         return self._finish(params, out, pose)
 
     def decode_step(self, params, x, pose, times, segment_ids,

@@ -31,7 +31,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (active_mesh, dp_shard_count,
@@ -151,11 +150,11 @@ class MoE:
                 return (buf[None], eid_s[None], tok_s[None], w_s[None],
                         pos[None])
 
-            buf, eid_s, tok_s, w_s, pos = shard_map(
+            buf, eid_s, tok_s, w_s, pos = jax.shard_map(
                 disp, mesh=mesh,
                 in_specs=(dspec, dspec, dspec),
                 out_specs=(dspec,) * 5,
-                check_rep=False)(xt, top_ids, weights)
+                check_vma=False)(xt, top_ids, weights)
         else:
             buf, eid_s, tok_s, w_s, pos = jax.vmap(
                 lambda a, b_, c: _dispatch_local(a, b_, c, cap, e))(
@@ -183,9 +182,9 @@ class MoE:
                                    pos_l[0], cap, tg)
                 return y[None]
 
-            y = shard_map(comb, mesh=mesh,
-                          in_specs=(dspec,) * 5, out_specs=dspec,
-                          check_rep=False)(eo, eid_s, tok_s, w_s, pos)
+            y = jax.shard_map(comb, mesh=mesh,
+                              in_specs=(dspec,) * 5, out_specs=dspec,
+                              check_vma=False)(eo, eid_s, tok_s, w_s, pos)
         else:
             y = jax.vmap(lambda a, b_, c, dd, ee: _combine_local(
                 a, b_, c, dd, ee, cap, tg))(eo, eid_s, tok_s, w_s, pos)

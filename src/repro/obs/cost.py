@@ -65,6 +65,13 @@ def compiled_cost(compiled: Any) -> Dict[str, float]:
                 rec[out] = float(v)
     except Exception:
         pass
+    try:
+        # Pallas kernels lower to Mosaic custom calls: their count shows
+        # whether a program runs the kernels or an XLA fallback
+        rec["kernel_calls"] = float(compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"'))
+    except Exception:
+        pass
     if "peak_bytes" not in rec:
         parts = [rec.get(k) for k in
                  ("argument_bytes", "output_bytes", "temp_bytes")]

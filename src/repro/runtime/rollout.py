@@ -21,11 +21,11 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core import kinematics
+from repro.distributed.sharding import without_mesh_rules
 from repro.scenarios.core import ScenarioConfig
 
 #: mesh axes a fleet engine partitions its scene slots over, in order
@@ -96,9 +96,9 @@ class RolloutEngine:
         """
         self.obs = registry if registry is not None else obs.get_registry()
         self.model = model
+        self.mesh = mesh
         self.params = params
         self.scen = scen_cfg
-        self.mesh = mesh
         self.num_slots = num_slots
         max_len = max_len or (scen_cfg.num_map
                               + scen_cfg.num_steps * scen_cfg.num_agents)
@@ -139,14 +139,18 @@ class RolloutEngine:
                           for k in cache_struct}
             self._cache_shardings = {
                 k: NamedSharding(mesh, s) for k, s in cache_spec.items()}
-            prefill_fn = shard_map(
-                prefill_fn, mesh=mesh,
+            # each device advances only its own lanes: the logical rules of
+            # an enclosing mesh (the training mesh, when the trainer's eval
+            # hook runs the engine) do not describe that code
+            lane_local = without_mesh_rules()
+            prefill_fn = jax.shard_map(
+                lane_local(prefill_fn), mesh=mesh,
                 in_specs=(P(), cache_spec, lane),
-                out_specs=(lane, cache_spec), check_rep=False)
-            step_fn = shard_map(
-                step_fn, mesh=mesh,
+                out_specs=(lane, cache_spec), check_vma=False)
+            step_fn = jax.shard_map(
+                lane_local(step_fn), mesh=mesh,
                 in_specs=(P(), cache_spec) + (lane,) * 6 + (P(),),
-                out_specs=(cache_spec,) + (lane,) * 4, check_rep=False)
+                out_specs=(cache_spec,) + (lane,) * 4, check_vma=False)
         # Donate the cache so XLA updates it in place: without donation
         # every tick round-trips the full preallocated K/V cache through
         # a copy, which dwarfs the attention work the decode kernel
@@ -162,6 +166,21 @@ class RolloutEngine:
             "rollout.step", registry=self.obs)
         self.ticks = 0
         self.last_actions = None      # (S, K, T_fut, A) after each run()
+
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        # A fleet engine's shard_map takes the parameters replicated (P());
+        # placing them here lets a caller hand in parameters laid out for
+        # another mesh over the same devices (the training mesh, from the
+        # trainer's eval hook) without the compiled step seeing a new
+        # input sharding.
+        if self.mesh is not None:
+            params = jax.device_put(params, NamedSharding(self.mesh, P()))
+        self._params = params
 
     def init_cache(self):
         cache = self.model.init_cache(self.num_slots, self.max_len,

@@ -37,6 +37,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.runtime.steps import make_train_step
         from repro.distributed.sharding import (sharding_for_specs,
             derive_opt_shardings, use_mesh_rules, batch_sharding)
+        from repro.launch.mesh import make_mesh_for
 
         cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
                           num_q_heads=4, num_kv_heads=2, d_ff=128,
@@ -54,7 +55,7 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         p1, o1, m1 = jax.jit(step)(params, opt_state, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh_for(8, model_axis=2)
         with use_mesh_rules(mesh):
             psh = sharding_for_specs(specs, mesh)
             osh = derive_opt_shardings(specs, jax.eval_shape(opt.init, params),
@@ -256,6 +257,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
         from repro.nn.transformer import TransformerLM
         from repro.distributed.sharding import (sharding_for_specs,
                                                 use_mesh_rules)
+        from repro.launch.mesh import make_mesh_for
 
         cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
                           num_q_heads=4, num_kv_heads=2, d_ff=128,
@@ -264,7 +266,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
         specs = model.specs()
         mgr = CheckpointManager({json.dumps(str(tmp_path))}, async_save=False)
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh_for(8, model_axis=2)
         psh_a = sharding_for_specs(specs, mesh_a)
         params = jax.device_put(nnm.init_params(specs, jax.random.key(0)),
                                 psh_a)
@@ -272,7 +274,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
 
         diffs = []
         for shape in ((2, 4), (8, 1)):
-            mesh_b = jax.make_mesh(shape, ("data", "model"))
+            mesh_b = make_mesh_for(8, model_axis=shape[1])
             psh_b = sharding_for_specs(specs, mesh_b)
             tree, _ = mgr.restore(1, shardings={{"params": psh_b}})
             diffs.append(max(float(jnp.max(jnp.abs(

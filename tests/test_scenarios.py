@@ -368,7 +368,8 @@ try:
 
     transl = st.floats(min_value=-4.0, max_value=4.0, allow_nan=False,
                        width=32)
-    angle = st.floats(min_value=-np.pi, max_value=np.pi, allow_nan=False,
+    angle = st.floats(min_value=-float(np.float32(np.pi)),
+                      max_value=float(np.float32(np.pi)), allow_nan=False,
                       width=32)
 
     @settings(max_examples=3, deadline=None, derandomize=True)

@@ -140,7 +140,8 @@ def _action_dists(encoding, z):
 
 
 transl = st.floats(min_value=-4.0, max_value=4.0, allow_nan=False, width=32)
-angle = st.floats(min_value=-np.pi, max_value=np.pi, allow_nan=False,
+angle = st.floats(min_value=-float(np.float32(np.pi)),
+                  max_value=float(np.float32(np.pi)), allow_nan=False,
                   width=32)
 
 

@@ -215,7 +215,8 @@ try:
 
     @settings(max_examples=4, deadline=None, derandomize=True)
     @given(order_seed=st.integers(0, 2 ** 16),
-           rate=st.floats(0.2, 3.0, allow_nan=False, width=32),
+           rate=st.floats(float(np.float32(0.2)), 3.0, allow_nan=False,
+                          width=32),
            num_slots=st.integers(1, 3))
     def test_metrics_invariant_to_arrival_schedule(order_seed, rate,
                                                    num_slots):

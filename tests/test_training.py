@@ -181,13 +181,19 @@ def test_sim_arch_reduced_trains_one_step():
 # ---------------------------------------------------------------------------
 
 _LOSS_CACHE = {}
+#: families whose scenes stay within the basis budget ``pos_scale`` targets
+#: (encoder-scaled |(x, y)| <= 3.54 here, plus <= 0.28 from the re-posing
+#: below); highway and on-ramp scenes reach 5.4 and are not in the domain
+#: the Fourier truncation is documented for
+_IN_BUDGET_FAMILIES = ("freeform", "pedestrian_crossing")
 
 
 def _training_loss(encoding, z):
     """BC loss of a fixed random model on one batch re-posed by z."""
     if encoding not in _LOSS_CACHE:
         model, params = _tiny_model(encoding, seed=7)
-        batch = _device_batch(make_sim_batch(11, 0, 2, SCEN))
+        batch = _device_batch(make_sim_batch(11, 0, 2, SCEN,
+                                             _IN_BUDGET_FAMILIES))
         eval_fn = jax.jit(make_sim_eval_step(model))
         _LOSS_CACHE[encoding] = (batch, eval_fn, params)
     batch, eval_fn, params = _LOSS_CACHE[encoding]
@@ -219,7 +225,8 @@ try:
 
     transl = st.floats(min_value=-4.0, max_value=4.0, allow_nan=False,
                        width=32)
-    angle = st.floats(min_value=-np.pi, max_value=np.pi, allow_nan=False,
+    angle = st.floats(min_value=-float(np.float32(np.pi)),
+                      max_value=float(np.float32(np.pi)), allow_nan=False,
                       width=32)
 
     @settings(max_examples=5, deadline=None, derandomize=True)
