@@ -214,5 +214,6 @@ def flash_attention_fwd(q, k, v, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q_segment_ids, k_segment_ids, q_times, k_times, q, k, v)
     return (out, lse[..., 0]) if return_lse else out

@@ -311,6 +311,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_dq",
     )(q_segment_ids, k_segment_ids, q_times, k_times,
       q, k, v, do, lse, delta)
 
@@ -363,6 +364,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q_segment_ids, k_segment_ids, q_times, k_times,
       q, do, lse, delta, k, v)
 

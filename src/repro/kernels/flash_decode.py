@@ -388,6 +388,7 @@ def flash_decode_fwd(q, k, v, kv_length, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(kvl, *inputs)
     return _combine_splits(o_p, m_p, l_p, q.dtype)
 

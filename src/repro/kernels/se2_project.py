@@ -161,6 +161,7 @@ def se2_fourier_project(x, pose, enc: SE2Fourier, mode: str, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="se2_project_k",
         )(pose32, x, const_nodes, proj)
     elif mode == "q":
         freqs = fourier.basis_frequencies(F).astype(np.float32)
@@ -181,6 +182,7 @@ def se2_fourier_project(x, pose, enc: SE2Fourier, mode: str, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="se2_project_q",
         )(pose32, x, basis_const)
     else:
         raise ValueError(f"mode must be 'q' or 'k', got {mode!r}")

@@ -78,9 +78,10 @@ def _scatter_rows(buf, new, cursor):
     (B, H, n, ...), cursor (B,) int32. The caller guarantees
     cursor + n <= S (dynamic_update_slice clamps, it does not wrap)."""
     axis = 1 if buf.ndim == 2 else buf.ndim - 2    # length axis of buf
-    return jax.vmap(
-        lambda b_, u, i: jax.lax.dynamic_update_slice_in_dim(
-            b_, u, i, axis=axis - 1))(buf, new, cursor)
+    with jax.named_scope("agent_sim.cache_write"):
+        return jax.vmap(
+            lambda b_, u, i: jax.lax.dynamic_update_slice_in_dim(
+                b_, u, i, axis=axis - 1))(buf, new, cursor)
 
 
 def _scatter_layer_rows(buf, layer, new, cursor):
@@ -99,10 +100,11 @@ def _scatter_layer_rows(buf, layer, new, cursor):
     assertion.
     """
     b = buf.shape[1]
-    for bi in range(b):
-        starts = (layer, bi, 0, cursor[bi]) + (0,) * (buf.ndim - 4)
-        buf = jax.lax.dynamic_update_slice(
-            buf, new[bi][None, None], starts)
+    with jax.named_scope("agent_sim.cache_write"):
+        for bi in range(b):
+            starts = (layer, bi, 0, cursor[bi]) + (0,) * (buf.ndim - 4)
+            buf = jax.lax.dynamic_update_slice(
+                buf, new[bi][None, None], starts)
     return buf
 
 
@@ -122,20 +124,21 @@ def install_slot_rows(cache, sub, si, n_rows: int):
     O(max_len) write per admission just to hide from that contract.
     """
     out = dict(cache)
-    for key in ("k", "v"):
-        rows = jax.lax.slice_in_dim(sub[key], 0, n_rows, axis=3)
-        out[key] = jax.lax.dynamic_update_slice(
-            cache[key], rows, (0, si, 0, 0, 0))
-    for key in ("k_scale", "v_scale"):
-        if key in cache:
+    with jax.named_scope("agent_sim.cache_write"):
+        for key in ("k", "v"):
             rows = jax.lax.slice_in_dim(sub[key], 0, n_rows, axis=3)
             out[key] = jax.lax.dynamic_update_slice(
-                cache[key], rows, (0, si, 0, 0))
-    for key in ("times", "seg"):
-        out[key] = jax.lax.dynamic_update_slice(
-            cache[key], sub[key][:, :n_rows], (si, 0))
-    out["cursor"] = jax.lax.dynamic_update_slice(
-        cache["cursor"], sub["cursor"], (si,))
+                cache[key], rows, (0, si, 0, 0, 0))
+        for key in ("k_scale", "v_scale"):
+            if key in cache:
+                rows = jax.lax.slice_in_dim(sub[key], 0, n_rows, axis=3)
+                out[key] = jax.lax.dynamic_update_slice(
+                    cache[key], rows, (0, si, 0, 0))
+        for key in ("times", "seg"):
+            out[key] = jax.lax.dynamic_update_slice(
+                cache[key], sub[key][:, :n_rows], (si, 0))
+        out["cursor"] = jax.lax.dynamic_update_slice(
+            cache["cursor"], sub["cursor"], (si,))
     return out
 
 
@@ -207,15 +210,17 @@ class SimAttention:
             p4 = pose[:, None]                       # (B, 1, n, 3)
             if self.enc.pose_dim == 2:
                 p4 = p4[..., :2]
-            q = self.enc.transform_q(q, p4)
-            k = self.enc.transform_k(k, p4)
-            if self.enc.transforms_values:
-                v = self.enc.transform_v(v, p4)
+            with jax.named_scope("agent_sim.se2_transform"):
+                q = self.enc.transform_q(q, p4)
+                k = self.enc.transform_k(k, p4)
+                if self.enc.transforms_values:
+                    v = self.enc.transform_v(v, p4)
         return q, k, v
 
     def _finish(self, params, out, pose):
         if self.enc is not None and self.enc.transforms_values:
-            out = self.enc.untransform_out(out, pose[:, None])
+            with jax.named_scope("agent_sim.se2_transform"):
+                out = self.enc.untransform_out(out, pose[:, None])
         return self.projs["o"](params["o"], _merge_heads(out))
 
     def __call__(self, params, x, pose, times, segment_ids):
@@ -287,14 +292,15 @@ class SimAttention:
                 kv_cache["v"], layer,
                 v_new.astype(kv_cache["v"].dtype), cursor)
         scale = 1.0 / float(cfg.head_dim) ** 0.5
-        out = kops.decode_attention(
-            q, kv_cache["k"], kv_cache["v"],
-            kv_length=cursor + n, layer=layer,
-            impl=impl or cfg.decode_impl or cfg.attn_impl,
-            scale=scale, q_times=times, k_times=cache_times,
-            q_segment_ids=segment_ids, k_segment_ids=cache_seg,
-            k_scale=kv_cache.get("k_scale"),
-            v_scale=kv_cache.get("v_scale"))
+        with jax.named_scope("agent_sim.decode_attention"):
+            out = kops.decode_attention(
+                q, kv_cache["k"], kv_cache["v"],
+                kv_length=cursor + n, layer=layer,
+                impl=impl or cfg.decode_impl or cfg.attn_impl,
+                scale=scale, q_times=times, k_times=cache_times,
+                q_segment_ids=segment_ids, k_segment_ids=cache_seg,
+                k_scale=kv_cache.get("k_scale"),
+                v_scale=kv_cache.get("v_scale"))
         return self._finish(params, out, pose), kv_cache
 
 
@@ -475,11 +481,13 @@ class AgentSimModel:
                 lp["attn"], h, enc_pose, times, segment_ids,
                 kv_cache, li, cache_times, cache_seg, cursor, impl=impl)
             x = x + attn_out
-            h = self.norm2(lp["norm2"], x)
-            x = x + self.mlp(lp["mlp"], h)
+            with jax.named_scope("agent_sim.mlp"):
+                h = self.norm2(lp["norm2"], x)
+                x = x + self.mlp(lp["mlp"], h)
 
-        x = self.final_norm(params["final_norm"], x)
-        logits = self.head(params["head"], x)
+        with jax.named_scope("agent_sim.head"):
+            x = self.final_norm(params["final_norm"], x)
+            logits = self.head(params["head"], x)
         new_cache = {**kv_cache, "times": cache_times,
                      "seg": cache_seg, "cursor": cursor + n}
         return logits, new_cache

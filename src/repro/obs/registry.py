@@ -29,15 +29,21 @@ Spans measure **host wall-clock between enter and exit** — for code that
 only *dispatches* async device work, that is dispatch + whatever the
 caller awaited, by design: the host pipeline is the thing being watched.
 Device-side truth comes from the optional ``jax.profiler`` integration
-(``--profile-dir`` on the launchers).
+(``--profile-dir`` on the launchers): an enabled span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a profile shows every
+span on the device trace's clock (a no-op TraceMe when no profiler
+session is running). The package never imports jax itself: a span opens
+the annotation only once the process has imported jax, as any process
+with a profiler session has.
 """
 from __future__ import annotations
 
 import math
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "NULL",
            "get_registry", "set_registry"]
@@ -198,24 +204,46 @@ class Histogram:
                 "buckets": {str(i): n for i, n in sorted(self.buckets.items())}}
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` once the process has imported jax,
+    else None: a profiler session needs jax, so before that no trace
+    exists for a span to join, and ``repro.obs`` stays importable
+    without jax."""
+    jax = sys.modules.get("jax")
+    return jax.profiler.TraceAnnotation if jax is not None else None
+
+
 class _Span:
     """Reusable timed region: records duration into ``<name>.seconds`` and
-    appends one complete ("ph": "X") trace event on exit."""
+    appends one complete ("ph": "X") trace event on exit; while open it
+    is also a profiler annotation of the same name, with the labels and
+    ``args`` as its arguments."""
 
-    __slots__ = ("_reg", "name", "labels", "_t0")
+    __slots__ = ("_reg", "name", "labels", "args", "_t0", "_ann")
 
-    def __init__(self, reg: "Registry", name: str, labels: Dict[str, Any]):
+    def __init__(self, reg: "Registry", name: str, labels: Dict[str, Any],
+                 args: Dict[str, Any]):
         self._reg = reg
         self.name = name
         self.labels = labels
+        self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        ann = _profiler_annotation()
+        if ann is not None:
+            self._ann = ann(self.name, **self.labels, **self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._reg.observe_span(self.name, self._t0, time.perf_counter(),
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        self._reg.observe_span(self.name, self._t0, t1, args=self.args,
                                **self.labels)
 
 
@@ -330,27 +358,32 @@ class Registry:
 
     # -- spans / events ------------------------------------------------------
 
-    def span(self, name: str, **labels):
+    def span(self, name: str, args: Optional[Dict[str, Any]] = None,
+             **labels):
         """``with registry.span("sim_server.tick"): ...`` — a monotonic
         wall-clock region; duration lands in the ``<name>.seconds``
-        histogram and as one Chrome trace event."""
+        histogram and as one Chrome trace event, and the region is a
+        ``jax.profiler`` annotation while it is open. ``labels`` key the
+        histogram series; ``args`` (e.g. a request's uid) ride only the
+        trace event and the annotation, so they cost no series each."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, labels)
+        return _Span(self, name, labels, args or {})
 
     def observe_span(self, name: str, t0: float, t1: float,
+                     args: Optional[Dict[str, Any]] = None,
                      **labels) -> None:
         """Record an already-measured ``perf_counter`` interval as if it
-        had run under :meth:`span` — for callers that only know after the
-        fact whether an interval should count (e.g. idle service ticks
-        are measured but not recorded)."""
+        had run under :meth:`span` (without the profiler annotation) —
+        for intervals measured elsewhere, e.g. per-rank step times."""
         if not self.enabled:
             return
         self.histogram(name + ".seconds", **labels).record(t1 - t0)
+        args = {**labels, **(args or {})}
         self._push_event({
             "name": name, "ph": "X", "pid": self.pid, "tid": self.tid(),
             "ts": (t0 - self.t0) * 1e6, "dur": (t1 - t0) * 1e6,
-            **({"args": labels} if labels else {})})
+            **({"args": args} if args else {})})
 
     def event(self, name: str, **labels) -> None:
         """Instant event (straggler flagged, slot evicted, run halted)."""

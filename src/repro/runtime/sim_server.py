@@ -146,9 +146,10 @@ class SimServer:
         in ``RolloutEngine``.
 
         ``registry``: telemetry home (``repro.obs``; ``None`` = process
-        default, ``obs.NULL`` = off). Every tick records a
-        ``sim_server.tick`` span plus occupancy / resident / queued
-        gauges from host-side bookkeeping; admissions record
+        default, ``obs.NULL`` = off). Every working tick records a
+        ``sim_server.tick`` span (its children: see ``tick``) plus
+        occupancy / resident / queued gauges from host-side
+        bookkeeping; admissions record
         ``sim_server.queue_wait.seconds`` (submit -> admit) and, once a
         lane's first closed-loop action drains,
         ``sim_server.first_action.seconds`` (admit -> first action on
@@ -278,7 +279,8 @@ class SimServer:
                 jax.random.fold_in(jax.random.key(req.seed), req.scene_id),
                 req.sample_id)
             tt = req.tensors
-            with self.obs.span("sim_server.admit"):
+            with self.obs.span("sim_server.admit",
+                               args={"uid": req.uid, "slot": si}):
                 self.cache, self.state = self._admit(
                     self.params, self.cache, self.state,
                     jnp.asarray(tt["map_feats"])[None],
@@ -354,15 +356,17 @@ class SimServer:
                    t, active, teacher):
         logits, pose, speed = state["logits"], state["pose"], state["speed"]
         proto, valid = state["proto"], state["valid"]
-        keys = jax.random.wrap_key_data(state["keys"])
-        keys_t = jax.vmap(jax.random.fold_in)(keys, t)
-        acts = jax.vmap(jax.random.categorical)(
-            keys_t, logits.astype(jnp.float32))              # (B, A)
-        ai, yi = jnp.divmod(acts, self.scen.yaw_bins)
-        new_pose, new_speed = step_kinematics(pose, speed, self._accel[ai],
-                                              self._yaw[yi])
-        new_pose = jnp.where(valid[..., None], new_pose, pose)
-        new_speed = jnp.where(valid, new_speed, speed)
+        with jax.named_scope("sim_server.sample"):
+            keys = jax.random.wrap_key_data(state["keys"])
+            keys_t = jax.vmap(jax.random.fold_in)(keys, t)
+            acts = jax.vmap(jax.random.categorical)(
+                keys_t, logits.astype(jnp.float32))          # (B, A)
+        with jax.named_scope("sim_server.kinematics"):
+            ai, yi = jnp.divmod(acts, self.scen.yaw_bins)
+            new_pose, new_speed = step_kinematics(
+                pose, speed, self._accel[ai], self._yaw[yi])
+            new_pose = jnp.where(valid[..., None], new_pose, pose)
+            new_speed = jnp.where(valid, new_speed, speed)
         tm = teacher[:, None]
         pose_in = jnp.where(tm[..., None], tpose, new_pose)
         speed_in = jnp.where(tm, tfeats[..., 0] * 10.0, new_speed)
@@ -392,45 +396,52 @@ class SimServer:
         Returns False when there was nothing to do (no resident or
         queued work). The device call is dispatched asynchronously;
         outputs are materialized ``drain_lag`` ticks later.
-        """
-        t0 = time.perf_counter()
-        ticked = self._tick_host()
-        # idle polls are free and would swamp the latency histogram with
-        # near-zero samples; only working ticks count as spans
-        if ticked:
-            self.obs.observe_span("sim_server.tick", t0, time.perf_counter())
-        return ticked
 
-    def _tick_host(self) -> bool:
+        A working tick is one ``sim_server.tick`` span whose children
+        mark the runtime's layers: ``sim_server.admit`` (one a lane),
+        ``assemble`` (host inputs and their transfers), ``dispatch`` (the
+        jitted tick call) and ``drain`` (where the host waits for an
+        earlier tick's outputs).
+        """
+        # idle polls are free and would swamp the latency histogram with
+        # near-zero samples; only working ticks open a span
+        if not self.queue and all(s.req is None for s in self.slots):
+            return False
+        with self.obs.span("sim_server.tick"):
+            self._tick_host()
+        return True
+
+    def _tick_host(self):
         self._admit_pending()
         b, a = self.num_slots, self.scen.num_agents
-        active = np.zeros(b, bool)
-        teacher = np.zeros(b, bool)
-        t_vec = np.zeros(b, np.int32)
-        tfeats = np.zeros((b, a, self.scen.agent_feat_dim), np.float32)
-        tpose = np.zeros((b, a, 3), np.float32)
-        tvalid = np.zeros((b, a), bool)
-        routes: List[Tuple[int, int, int]] = []
-        for si, slot in enumerate(self.slots):
-            req = slot.req
-            if req is None:
-                continue
-            active[si] = True
-            t_vec[si] = slot.t
-            if slot.t < req.t_hist:
-                teacher[si] = True
-                tt = req.tensors
-                tfeats[si] = tt["agent_feats"][slot.t]
-                tpose[si] = tt["agent_pose"][slot.t]
-                tvalid[si] = tt["agent_valid"][slot.t]
-            else:
-                routes.append((si, req.uid, slot.t - req.t_hist))
-        if not active.any():
-            return False
-        self.cache, self.state, acts, pose = self._tick(
-            self.params, self.cache, self.state, jnp.asarray(tfeats),
-            jnp.asarray(tpose), jnp.asarray(tvalid), jnp.asarray(t_vec),
-            jnp.asarray(active), jnp.asarray(teacher))
+        with self.obs.span("sim_server.assemble"):
+            active = np.zeros(b, bool)
+            teacher = np.zeros(b, bool)
+            t_vec = np.zeros(b, np.int32)
+            tfeats = np.zeros((b, a, self.scen.agent_feat_dim), np.float32)
+            tpose = np.zeros((b, a, 3), np.float32)
+            tvalid = np.zeros((b, a), bool)
+            routes: List[Tuple[int, int, int]] = []
+            for si, slot in enumerate(self.slots):
+                req = slot.req
+                if req is None:
+                    continue
+                active[si] = True
+                t_vec[si] = slot.t
+                if slot.t < req.t_hist:
+                    teacher[si] = True
+                    tt = req.tensors
+                    tfeats[si] = tt["agent_feats"][slot.t]
+                    tpose[si] = tt["agent_pose"][slot.t]
+                    tvalid[si] = tt["agent_valid"][slot.t]
+                else:
+                    routes.append((si, req.uid, slot.t - req.t_hist))
+            inputs = (jnp.asarray(tfeats), jnp.asarray(tpose),
+                      jnp.asarray(tvalid), jnp.asarray(t_vec),
+                      jnp.asarray(active), jnp.asarray(teacher))
+        with self.obs.span("sim_server.dispatch"):
+            self.cache, self.state, acts, pose = self._tick(
+                self.params, self.cache, self.state, *inputs)
         self.ticks += 1
         if routes:
             self._pending.append((routes, acts, pose))
@@ -440,7 +451,8 @@ class SimServer:
             slot.t += 1
             if slot.t >= slot.req.t_total:      # horizon: retire, free slot
                 slot.req = None
-        self._drain(self.drain_lag)
+        with self.obs.span("sim_server.drain"):
+            self._drain(self.drain_lag)
         if self.obs.enabled:
             m = self.scen.num_map
             live = sum(min(m + s.t * a, self.max_len)
@@ -452,7 +464,6 @@ class SimServer:
             self.obs.gauge("sim_server.resident").set(
                 sum(s.req is not None for s in self.slots))
             self.obs.gauge("sim_server.queued").set(len(self.queue))
-        return True
 
     # -- slot health / quarantine ---------------------------------------------
 

@@ -144,6 +144,11 @@ def test_span_records_histogram_and_event():
     # observe_span: same shape, caller-measured interval
     r.observe_span("work", 0.0, 1.0, phase="a")
     assert h.count == 2
+    # args ride the trace event only: no histogram series of their own
+    with r.span("work", args={"uid": 7}, phase="a"):
+        pass
+    assert h.count == 3
+    assert r.events()[-1]["args"] == {"phase": "a", "uid": 7}
 
 
 def test_trace_ring_drops_oldest_half_at_capacity():
@@ -270,6 +275,101 @@ def test_sim_server_telemetry_content():
     tick_spans = [e for e in reg.events()
                   if e.get("ph") == "X" and e["name"] == "sim_server.tick"]
     assert len(tick_spans) == int(stats["ticks"])
+
+
+# ---------------------------------------------------------------------------
+# Program spans on the profiler clock; the tick's layers named in its HLO
+# ---------------------------------------------------------------------------
+
+TICK_CHILDREN = ("sim_server.assemble", "sim_server.dispatch",
+                 "sim_server.drain")
+
+
+def _profiled_host_events(trace_dir, prefix):
+    """(name, start_s, end_s, stats) of the host events whose name starts
+    with ``prefix`` in the profiler trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns * 1e-9,
+                         (e.start_ns + e.duration_ns) * 1e-9, dict(e.stats))
+                        for e in line.events if e.name.startswith(prefix)]
+    return sorted(out, key=lambda ev: ev[1])
+
+
+def test_sim_server_spans_nest_on_the_profiler_clock(tmp_path):
+    reg = obs.Registry()
+    srv = SimServer(MODEL, PARAMS, SCEN, num_slots=2, registry=reg)
+    for i, s in enumerate(SCENES[:3]):
+        srv.submit(SceneRequest(uid=i, tensors=s, t_hist=T_HIST))
+    srv.tick()                             # compiles; admits lanes 0 and 1
+    ticks0 = srv.ticks
+    with jax.profiler.trace(str(tmp_path)):
+        # lane 2 is admitted when a lane retires at its horizon
+        while srv.admitted < 3:
+            srv.tick()
+        srv.run_until_drained()
+        n_ticks = srv.ticks - ticks0
+        assert srv.tick() is False         # an idle poll
+    assert reg.histogram("sim_server.tick.seconds").count == srv.ticks
+    for name in TICK_CHILDREN:
+        assert reg.histogram(name + ".seconds").count == srv.ticks
+
+    evs = _profiled_host_events(str(tmp_path), "sim_server.")
+    ticks = [e for e in evs if e[0] == "sim_server.tick"]
+    assert len(ticks) == n_ticks           # the idle poll opened no span
+    for name, t0, t1, _ in evs:
+        if name == "sim_server.tick":
+            continue
+        # every child lies inside exactly one tick, on the same clock
+        assert sum(s <= t0 and t1 <= e for _, s, e, _ in ticks) == 1, name
+    for _, s, e, _ in ticks:
+        inside = [n for n, t0, t1, _ in evs if s <= t0 and t1 <= e
+                  and n in TICK_CHILDREN]
+        assert sorted(inside) == sorted(TICK_CHILDREN)
+    # an admission's span carries its lane: uid and slot
+    (admit,) = [e for e in evs if e[0] == "sim_server.admit"]
+    assert admit[3]["uid"] == 2 and admit[3]["slot"] in (0, 1)
+
+
+def _tick_hlo(encoding):
+    """The tick of a tiny two-slot server, compiled: its HLO text with each
+    op's metadata (the ``named_scope`` path)."""
+    cfg = AgentSimConfig(d_model=32, num_layers=2, num_heads=2, head_dim=12,
+                         d_ff=64, num_actions=SCEN.num_actions,
+                         encoding=encoding, attn_impl="ref")
+    model = AgentSimModel(cfg)
+    params = nnm.init_params(model.specs(), jax.random.key(0))
+    srv = SimServer(model, params, SCEN, num_slots=2, registry=obs.NULL)
+    b, a = srv.num_slots, SCEN.num_agents
+    args = (params, srv.cache, srv.state,
+            jnp.zeros((b, a, SCEN.agent_feat_dim)), jnp.zeros((b, a, 3)),
+            jnp.ones((b, a), bool), jnp.zeros((b,), jnp.int32),
+            jnp.ones((b,), bool), jnp.zeros((b,), bool))
+    hlo = jax.jit(srv._tick_impl).lower(*args).compile().as_text()
+    return hlo, srv.cache["k"].shape
+
+
+@pytest.mark.parametrize("encoding", ["se2_fourier", "absolute"])
+def test_tick_hlo_names_its_layers(encoding):
+    hlo, k_shape = _tick_hlo(encoding)
+    k_type = "f32[" + ",".join(map(str, k_shape)) + "]"
+    writes = [ln for ln in hlo.splitlines()
+              if " dynamic-update-slice(" in ln and f"= {k_type}" in ln]
+    assert writes and all("agent_sim.cache_write" in ln for ln in writes)
+    for scope in ("agent_sim.decode_attention", "agent_sim.mlp",
+                  "agent_sim.head", "sim_server.sample",
+                  "sim_server.kinematics"):
+        assert scope in hlo, scope
+    # the absolute baseline has no SE(2) transform to name
+    assert ("agent_sim.se2_transform" in hlo) == (encoding == "se2_fourier")
 
 
 # ---------------------------------------------------------------------------

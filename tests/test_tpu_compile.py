@@ -12,6 +12,7 @@ only one process at a time may load libtpu, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +56,14 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compile_for_chip(fn, *structs):
+def _compile_for_chip(fn, *structs, kernels=()):
     compiled = jax.jit(fn).lower(*structs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # each kernel's custom call is named after the kernel, so a device
+    # trace shows which kernel ran
+    for name in kernels:
+        assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name
     return compiled
 
 
@@ -84,7 +90,7 @@ def test_flash_decode_layer_stacked_cache_compiles(one_chip, arch,
             layer=LAYERS // 2, q_times=qt, k_times=kt, q_segment_ids=qs,
             k_segment_ids=ks, k_scale=k_scale, v_scale=v_scale)
 
-    _compile_for_chip(decode, *args)
+    _compile_for_chip(decode, *args, kernels=("flash_decode",))
 
 
 def _flash_structs(one_chip, c=ROW_WIDTHS["sim-se2-fourier"]):
@@ -105,7 +111,8 @@ def _flash(q, k, v, times, seg):
 
 def test_flash_forward_compiles(one_chip):
     qkv, row = _flash_structs(one_chip)
-    _compile_for_chip(_flash, qkv, qkv, qkv, row, row)
+    _compile_for_chip(_flash, qkv, qkv, qkv, row, row,
+                      kernels=("flash_attention",))
 
 
 def test_flash_forward_backward_compiles(one_chip):
