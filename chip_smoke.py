@@ -148,6 +148,8 @@ def kernels_phase(device, model, arch, seed):
     k, v = normal(layers, b, h, slab, c), normal(layers, b, h, slab, c)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
+    # the model's stacked cache is feature-major: (L, B, H, c, S)
+    k, v, kq, vq = (jnp.swapaxes(x, -1, -2) for x in (k, v, kq, vq))
     errs, twin_errs, compile_s, kernels = {}, {}, 0.0, 0
 
     def compare(names, got, twin, want, keep=1.0):

@@ -161,25 +161,30 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
     # fetch to an already-resident block) and no compute.
     live = jnp.logical_and(jk < num_k_blocks, k_start < kvl)
 
-    kv_idx = (0, 0, 0) if layered else (0, 0)    # layer-stacked cache tiles
+    # A layer-stacked cache tile is feature-major, (d, bk) / (dv, bk) with
+    # the tokens in the lanes; an unstacked one is (bk, d) / (bk, dv).
+    kv_idx = (0, 0, 0) if layered else (0, 0)
+    tok_ax = 1 if layered else 0                 # token axis of a k/v tile
 
     def row_scale(ref):
         # the scale tile spans every kv head (a one-head (1, block_k) tile
-        # is not a legal block); pick this program's head, as a column
-        row = ref[0, 0, hk, :] if layered else ref[0, hk, :]
-        return _column(row)                          # (bk, 1)
+        # is not a legal block); pick this program's head: a (1, bk) lane
+        # row for a feature-major tile, a (bk, 1) column otherwise
+        if layered:
+            return ref[0, 0, hk, :]
+        return _column(ref[0, hk, :])
 
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (bq, d)
-        k = k_ref[kv_idx].astype(jnp.float32)        # (bk, d)
-        v = v_ref[kv_idx].astype(jnp.float32)        # (bk, dv)
+        k = k_ref[kv_idx].astype(jnp.float32)
+        v = v_ref[kv_idx].astype(jnp.float32)
         if quant_k:
             k = k * row_scale(k_scale_ref)
         if quant_v:
             v = v * row_scale(v_scale_ref)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1 - tok_ax,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
         cols = jax.lax.broadcasted_iota(
@@ -199,8 +204,10 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
         # 0 * NaN is NaN, and rows beyond the cursor may carry any bit
         # pattern (a quarantined predecessor's NaN rows included). For
         # finite stale rows this is an exact no-op (0 * finite == 0).
-        reachable = jnp.any(mask, axis=0, keepdims=True)
-        v = jnp.where(_column(reachable.astype(jnp.float32)) > 0, v, 0.0)
+        reachable = jnp.any(mask, axis=0, keepdims=True)      # (1, bk)
+        if not layered:
+            reachable = _column(reachable.astype(jnp.float32)) > 0
+        v = jnp.where(reachable, v, 0.0)
 
         m_prev = m_s[:, 0]
         l_prev = l_s[:, 0]
@@ -212,7 +219,7 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
             (l_prev * alpha + jnp.sum(p, axis=-1))[:, None], l_s.shape)
         m_s[...] = jnp.broadcast_to(m_new[:, None], m_s.shape)
         acc_s[...] = acc_s[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (tok_ax,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(ik == blocks_per_split - 1)
@@ -258,19 +265,21 @@ def flash_decode_fwd(q, k, v, kv_length, *,
     (B, Hkv, Sk) float32 mark the cache as int8-quantized. Returns
     (B, Hq, Sq, Dv) in q.dtype.
 
-    With ``layer=i`` (static int) the cache operands carry the model's
-    leading layer axis — k (L, B, Hkv, Sk, D), v (L, B, Hkv, Sk, Dv),
-    scales (L, B, Hkv, Sk) — and the BlockSpec index maps address layer
-    ``i`` directly, so no per-layer (B, Hkv, Sk, .) slice of the stacked
-    cache is ever materialized (see :func:`decode_ragged_xla`).
+    With ``layer=i`` (static int) the cache operands are the model's
+    layer-stacked, feature-major buffers — k (L, B, Hkv, D, Sk), v (L, B,
+    Hkv, Dv, Sk), scales (L, B, Hkv, Sk) — and the BlockSpec index maps
+    address layer ``i`` directly, so no per-layer slice of the stacked
+    cache is ever materialized (see :func:`decode_ragged_xla`). Their
+    tiles are (D, block_k) / (Dv, block_k): ``q @ k`` is a plain matmul
+    and ``p @ v^T`` contracts both operands' token axes.
     """
     b, hq, sq, d = q.shape
     if layer is None:
         _, hkv, sk, dv = v.shape
         assert k.shape == (b, hkv, sk, d), (q.shape, k.shape, v.shape)
     else:
-        nl, _, hkv, sk, dv = v.shape
-        assert k.shape == (nl, b, hkv, sk, d), (q.shape, k.shape, v.shape)
+        nl, _, hkv, dv, sk = v.shape
+        assert k.shape == (nl, b, hkv, d, sk), (q.shape, k.shape, v.shape)
         assert 0 <= layer < nl, (layer, nl)
     assert hq % hkv == 0, (hq, hkv)
     assert sk % block_k == 0, (sk, block_k)
@@ -320,15 +329,15 @@ def flash_decode_fwd(q, k, v, kv_length, *,
         kdv_block = (1, 1, block_k, dv)
     else:
         def kv_map(b_, h, s, ik, kvl_ref):
-            return (layer, b_, h // group,
-                    _clamped(s * bps + ik, kvl_ref[b_]), 0)
+            return (layer, b_, h // group, 0,
+                    _clamped(s * bps + ik, kvl_ref[b_]))
 
         def kvec_map(b_, h, s, ik, kvl_ref):
             return (layer, b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
 
         kv_block = (1, 1, hkv, block_k)
-        kd_block = (1, 1, 1, block_k, d)
-        kdv_block = (1, 1, 1, block_k, dv)
+        kd_block = (1, 1, 1, d, block_k)
+        kdv_block = (1, 1, 1, dv, block_k)
 
     def krow_map(b_, h, s, ik, kvl_ref):
         return (b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
@@ -435,15 +444,18 @@ def flash_decode(q, k, v, kv_length, *,
     operand is needed. Inference-only (no custom_vjp): the decode path
     never differentiates.
 
-    With ``layer`` set (layer-stacked (L, B, H, S, .) cache operands),
-    the cache is consumed **in place** and must already be token-aligned:
-    ``S % block_k == 0`` (or ``S <= block_k``, which shrinks the block) —
-    padding it here would copy the whole preallocated buffer every call.
-    ``RolloutEngine`` rounds ``max_len`` up to a 128 multiple for exactly
-    this reason.
+    With ``layer`` set (layer-stacked, feature-major (L, B, H, c, S)
+    cache operands), the cache is consumed **in place** and must already
+    be token-aligned: ``S % block_k == 0`` (or ``S <= block_k``, which
+    shrinks the block) — padding it here would copy the whole
+    preallocated buffer every call. ``RolloutEngine`` and ``SimServer``
+    round ``max_len`` up to a 128 multiple for exactly this reason.
     """
     b, hq, sq, d = q.shape
-    sk, dv = v.shape[-2], v.shape[-1]
+    if layer is None:
+        sk, dv = v.shape[-2], v.shape[-1]
+    else:
+        dv, sk = v.shape[-2], v.shape[-1]
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     q = _pad_axis(q, 16, 2)
@@ -464,8 +476,9 @@ def flash_decode(q, k, v, kv_length, *,
                 f"would copy the whole cache every tick — allocate "
                 f"max_len rounded up to a multiple of {block_k}")
         # Feature dims are consumed as allocated (padding would copy the
-        # cache); on real TPU, allocate them 128-aligned for full MXU
-        # tiles — interpret mode and the XLA twin don't care.
+        # cache). They sit on the tiles' sublane axis, with the
+        # 128-aligned tokens in the lanes, so a width that is a multiple
+        # of 8 (200, 24) tiles with no padding at all.
     if q_segment_ids is not None:
         q_segment_ids = _pad_axis(q_segment_ids, 16, 1, value=0)
         k_segment_ids = _pad_axis(k_segment_ids, block_k, 1, value=-1)
@@ -508,13 +521,13 @@ def decode_ragged_xla(q, k, v, kv_length, *,
       ``S - block_k`` and masks the re-read rows out (``cols >= start``)
       so every row is folded exactly once.
     * **Layer-stacked caches are sliced in place.** With ``layer=i``
-      (a static int), ``k``/``v`` are the model's full stacked
-      ``(L, B, Hkv, S, .)`` cache buffers and every block read is a
-      single ``dynamic_slice`` at ``(i, 0, 0, start, 0)`` — the per-layer
-      ``(B, Hkv, S, .)`` view is never materialized. (Slicing the layer
-      out first — e.g. threading the cache through ``lax.scan`` xs/ys —
-      copies O(max_len) per layer per tick and silently erases the
-      ragged win; that is exactly the regression
+      (a static int), ``k``/``v`` are the model's full stacked,
+      feature-major ``(L, B, Hkv, c, S)`` cache buffers and every block
+      read is a single ``dynamic_slice`` at ``(i, 0, 0, 0, start)`` —
+      the per-layer ``(B, Hkv, c, S)`` view is never materialized.
+      (Slicing the layer out first — e.g. threading the cache through
+      ``lax.scan`` xs/ys — copies O(max_len) per layer per tick and
+      silently erases the ragged win; that is exactly the regression
       ``benchmarks/rollout_bench.py`` pins.)
 
     Quantized caches are dequantized one block at a time inside the
@@ -522,10 +535,11 @@ def decode_ragged_xla(q, k, v, kv_length, *,
     kernel's per-tile VMEM dequant.
     """
     b, hq, sq, d = q.shape
-    if layer is None:
-        _, hkv, sk, dv = v.shape
+    feat_major = layer is not None       # stacked cache: tokens last
+    if feat_major:
+        _, _, hkv, dv, sk = v.shape
     else:
-        _, _, hkv, sk, dv = v.shape
+        _, hkv, sk, dv = v.shape
     group = hq // hkv
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
@@ -552,20 +566,28 @@ def decode_ragged_xla(q, k, v, kv_length, *,
         out = jax.lax.dynamic_slice(arr, starts, sizes)
         return out[0] if layer is not None else out
 
+    # a k/v block is (B, H, c, bk) feature-major, else (B, H, bk, c); a
+    # per-token (B, H, bk) row broadcasts along its token axis
+    tok = 1 if feat_major else 2
+    kv_eq = "bhdm" if feat_major else "bhmd"
+
+    def row(x):
+        return x[..., None, :] if feat_major else x[..., None]
+
     def body(i, carry):
         m, l, acc = carry
         start_u = i * block_k                       # nominal block start
         start = jnp.minimum(start_u, sk - block_k)  # clamped (last block)
-        kc = block_slice(k, start, block_k, 2).astype(jnp.float32)
-        vc = block_slice(v, start, block_k, 2).astype(jnp.float32)
+        kc = block_slice(k, start, block_k, tok).astype(jnp.float32)
+        vc = block_slice(v, start, block_k, tok).astype(jnp.float32)
         if k_scale is not None:
-            kc = kc * block_slice(k_scale, start, block_k, 1)[..., None]
+            kc = kc * row(block_slice(k_scale, start, block_k, 1))
         if v_scale is not None:
-            vc = vc * block_slice(v_scale, start, block_k, 1)[..., None]
+            vc = vc * row(block_slice(v_scale, start, block_k, 1))
         if group > 1:
             kc = jnp.repeat(kc, group, axis=1)
             vc = jnp.repeat(vc, group, axis=1)
-        s = jnp.einsum("bhnd,bhmd->bhnm", qf, kc) * scale
+        s = jnp.einsum(f"bhnd,{kv_eq}->bhnm", qf, kc) * scale
         cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, block_k), 3) \
             + start
         # rows before the nominal start were folded by an earlier block
@@ -585,13 +607,13 @@ def decode_ragged_xla(q, k, v, kv_length, *,
         # 0 * NaN is NaN, and rows beyond the cursor may carry any bit
         # pattern (a quarantined predecessor's NaN rows included). For
         # finite stale rows this is an exact no-op (0 * finite == 0).
-        vc = jnp.where(mask.any(axis=(1, 2))[:, None, :, None], vc, 0.0)
+        vc = jnp.where(row(mask.any(axis=(1, 2))[:, None]), vc, 0.0)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m_new)
         p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
         l_new = l * alpha + jnp.sum(p, axis=-1)
         acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhnm,bhmd->bhnd", p, vc)
+            f"bhnm,{kv_eq}->bhnd", p, vc)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((b, hq, sq), _NEG_INF, jnp.float32)

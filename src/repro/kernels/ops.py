@@ -391,10 +391,11 @@ def decode_attention(q, k, v, *, kv_length, impl: str = "auto",
 
     ``k_scale``/``v_scale`` (B, Hkv, Sk) float32 mark ``k``/``v`` as int8
     caches with per-(head, token) scales. ``layer`` (static int) marks
-    ``k``/``v`` (and scales) as the model's layer-stacked
-    ``(L, B, Hkv, Sk, .)`` cache buffers, which the ragged paths index in
-    place — the per-layer slice is never materialized (the generic
-    fallbacks *do* materialize it; they are O(max_len) oracles either
+    ``k``/``v`` as the model's layer-stacked, feature-major
+    ``(L, B, Hkv, c, Sk)`` cache buffers (scales ``(L, B, Hkv, Sk)``),
+    which the ragged paths index in place — the per-layer slice is never
+    materialized (the generic fallbacks *do* materialize it, and swap it
+    back to ``(B, Hkv, Sk, c)``; they are O(max_len) oracles either
     way). Masking semantics (block-causal ``q_times``/``k_times``,
     segment ids, GQA) match :func:`attention` with ``causal=True``;
     decode is inference-only, so none of these paths define a VJP.
@@ -418,8 +419,8 @@ def decode_attention(q, k, v, *, kv_length, impl: str = "auto",
             layer=layer)
     if impl in ("ref", "chunked", "flash"):
         if layer is not None:
-            k = k[layer]
-            v = v[layer]
+            k = jnp.swapaxes(k[layer], -1, -2)
+            v = jnp.swapaxes(v[layer], -1, -2)
             k_scale = None if k_scale is None else k_scale[layer]
             v_scale = None if v_scale is None else v_scale[layer]
         if k_scale is not None:

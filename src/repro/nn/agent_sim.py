@@ -87,22 +87,27 @@ def _scatter_rows(buf, new, cursor):
 def _scatter_layer_rows(buf, layer, new, cursor):
     """Write one layer's new rows into the *stacked* cache in place.
 
-    buf (L, B, H, S, c) or (L, B, H, S); new (B, H, n, c) / (B, H, n);
-    layer a static int; cursor (B,). A chain of per-slot
-    ``dynamic_update_slice`` ops, each touching only the n written rows
-    of (layer, slot) — under jit with a donated cache the whole update
-    is O(B * n), not O(max_len). The tempting alternatives both
-    silently copy the entire preallocated buffer every tick and erase
-    the ragged-decode win: threading the cache through ``lax.scan``
-    xs/ys (slice-in/stack-out copies), and ``vmap`` over the slot axis
-    (in_axes=1 inserts full-buffer transposes). The engine-level
-    regression guard is ``benchmarks/rollout_bench.py``'s flatness
-    assertion.
+    buf (L, B, H, c, S) or (L, B, H, S), tokens last (see
+    ``AgentSimModel.init_cache``); new (B, H, n, c) / (B, H, n), as the
+    projections produce them — k/v rows are laid out (B, H, c, n) here
+    and land at lane offset ``cursor``; layer a static int; cursor (B,).
+    A chain of per-slot ``dynamic_update_slice`` ops, each touching only
+    the n written rows of (layer, slot) — under jit with a donated cache
+    the whole update is O(B * n), not O(max_len). The tempting
+    alternatives both silently copy the entire preallocated buffer every
+    tick and erase the ragged-decode win: threading the cache through
+    ``lax.scan`` xs/ys (slice-in/stack-out copies), and ``vmap`` over
+    the slot axis (in_axes=1 inserts full-buffer transposes). The
+    engine-level regression guard is ``benchmarks/rollout_bench.py``'s
+    flatness assertion; ``tests/test_tpu_compile.py`` checks that the
+    compiled v5e tick holds no copy of the cache.
     """
+    if new.ndim == 4:
+        new = jnp.swapaxes(new, -1, -2)
     b = buf.shape[1]
     with jax.named_scope("agent_sim.cache_write"):
         for bi in range(b):
-            starts = (layer, bi, 0, cursor[bi]) + (0,) * (buf.ndim - 4)
+            starts = (layer, bi) + (0,) * (buf.ndim - 3) + (cursor[bi],)
             buf = jax.lax.dynamic_update_slice(
                 buf, new[bi][None, None], starts)
     return buf
@@ -125,15 +130,13 @@ def install_slot_rows(cache, sub, si, n_rows: int):
     """
     out = dict(cache)
     with jax.named_scope("agent_sim.cache_write"):
-        for key in ("k", "v"):
-            rows = jax.lax.slice_in_dim(sub[key], 0, n_rows, axis=3)
-            out[key] = jax.lax.dynamic_update_slice(
-                cache[key], rows, (0, si, 0, 0, 0))
-        for key in ("k_scale", "v_scale"):
+        for key in AgentSimModel._LAYER_CACHE_KEYS:
             if key in cache:
-                rows = jax.lax.slice_in_dim(sub[key], 0, n_rows, axis=3)
+                # every stacked array keeps its tokens on the last axis
+                rows = jax.lax.slice_in_dim(sub[key], 0, n_rows, axis=-1)
+                starts = (0, si) + (0,) * (rows.ndim - 2)
                 out[key] = jax.lax.dynamic_update_slice(
-                    cache[key], rows, (0, si, 0, 0))
+                    cache[key], rows, starts)
         for key in ("times", "seg"):
             out[key] = jax.lax.dynamic_update_slice(
                 cache[key], sub[key][:, :n_rows], (si, 0))
@@ -244,17 +247,18 @@ class SimAttention:
         """Incremental decode: attend ``n`` new tokens over the cache.
 
         x (B, n, d_model); pose (B, n, 3) *encoder-scaled*; times (B, n);
-        segment_ids (B, n); ``kv_cache`` is the model's layer-STACKED
-        cache: ``{"k": (L, B, H, S_max, c), "v": (L, B, H, S_max, cv)}``
-        plus, for int8 caches, per-(head, token) ``"k_scale"``/
-        ``"v_scale"`` (L, B, H, S_max) float32 living beside the rows
-        they scale; ``layer`` is this layer's static index. The stacked
-        buffers are written with O(n) in-place scatters and read by the
-        ragged decode paths through in-place (layer, block) slices — a
-        per-layer (B, H, S_max, .) copy never exists. cache_times /
-        cache_seg (B, S_max) are **already updated** with the new tokens'
-        rows (they are layer-independent, so the model writes them once);
-        cursor (B,) — rows written *before* this call. Returns
+        segment_ids (B, n); ``kv_cache`` is the model's layer-STACKED,
+        feature-major cache: ``{"k": (L, B, H, c, S_max), "v": (L, B,
+        H, cv, S_max)}`` plus, for int8 caches, per-(head, token)
+        ``"k_scale"``/``"v_scale"`` (L, B, H, S_max) float32 living
+        beside the rows they scale; ``layer`` is this layer's static
+        index. The stacked buffers are written with O(n) in-place
+        scatters and read by the ragged decode paths through in-place
+        (layer, block) slices — a per-layer copy never exists.
+        cache_times / cache_seg (B, S_max) are **already updated** with
+        the new tokens' rows (they are layer-independent, so the model
+        writes them once); cursor (B,) — rows written *before* this
+        call. Returns
         (out (B, n, d_model), updated kv_cache).
 
         New rows are written at [cursor, cursor + n) — quantized on
@@ -423,6 +427,24 @@ class AgentSimModel:
         plus layer-independent times / segment ids / per-slot cursors.
         Segment ids start at -1, so unwritten rows are always masked.
 
+        k and v are stored **feature-major**, ``(L, B, H, c, S)``: the
+        token axis is last. A TPU tiles an array's last two axes as
+        (sublane, lane) = (8, 128) for float32, and S is 128-aligned by
+        every caller (``SimServer``, ``RolloutEngine``), so the tokens
+        fill the lanes and any row width c that is a multiple of 8 (200
+        for se2_fourier, 24 for absolute) tiles with no padding. The
+        row-major layout is then the compact one that the runtime picks
+        for the donated buffer, and the decode kernel and the in-place
+        writes use it as is. Rows last, ``(L, B, H, S, c)``, put c in
+        the lanes: unless c is a multiple of 128 that layout is padded,
+        the runtime stores the buffer S-minor instead, and every tick
+        copies the whole cache between the two (and XLA's
+        rematerializer compresses and restores it around each layer).
+        When c is a multiple of 128 neither layout pads, so one layout
+        serves every encoding. The scales ``(L, B, H, S)`` are S-minor
+        already. (The LM stack's unstacked ``Attention`` cache keeps
+        ``(B, H, S, D)``.)
+
         ``dtype`` selects the cache storage dtype: a jnp dtype or one of
         the strings "float32" / "bfloat16" / "int8" (the
         ``RolloutEngine(cache_dtype=...)`` spelling). int8 caches carry
@@ -436,8 +458,8 @@ class AgentSimModel:
         ck, cv = self.attn.cache_dims
         l, b, h, s = cfg.num_layers, batch_size, cfg.num_heads, max_len
         cache = {
-            "k": jnp.zeros((l, b, h, s, ck), dtype),
-            "v": jnp.zeros((l, b, h, s, cv), dtype),
+            "k": jnp.zeros((l, b, h, ck, s), dtype),
+            "v": jnp.zeros((l, b, h, cv, s), dtype),
             "times": jnp.zeros((b, s), jnp.int32),
             "seg": jnp.full((b, s), -1, jnp.int32),
             "cursor": jnp.zeros((b,), jnp.int32),

@@ -132,7 +132,7 @@ class RolloutEngine:
             self.num_slots = -(-num_slots // shards) * shards
             lane = P(lane_axes if len(lane_axes) > 1 else lane_axes[0])
             # cache leaves: layer-stacked K/V rows carry the slot axis at
-            # dim 1 (L, B, H, S, .); times/seg/cursor carry it at dim 0
+            # dim 1 (L, B, H, c, S); times/seg/cursor carry it at dim 0
             cache_struct = jax.eval_shape(self.init_cache)
             stacked = set(model._LAYER_CACHE_KEYS)
             cache_spec = {k: (P(None, *lane) if k in stacked else lane)

@@ -8,9 +8,9 @@ slots and evicted at their horizon *while every other slot keeps
 ticking*, so heavy traffic streams through one resident jitted tick with
 exactly one compilation. The moving parts:
 
-* **Slab KV cache.** All concurrent scenes share ONE layer-stacked
-  ``(L, B, H, S_slab, ·)`` cache (f32 / bf16 / int8 + scales — PR 5's
-  in-place plumbing) instead of each scene paying its own ``max_len``
+* **Slab KV cache.** All concurrent scenes share ONE layer-stacked,
+  feature-major ``(L, B, H, c, S_slab)`` cache (f32 / bf16 / int8 +
+  scales) instead of each scene paying its own ``max_len``
   allocation + compile. A retiring scene frees its slot immediately; the
   successor's rows simply overwrite the prefix. Rows the predecessor
   left beyond the reset cursor are **not scrubbed** — they are provably

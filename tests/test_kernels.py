@@ -11,6 +11,7 @@ import pytest
 
 from repro.core import encodings
 from repro.kernels import ops, ref
+from repro.kernels.flash_decode import dequantize_kv, quantize_kv
 from repro.kernels.se2_project import se2_fourier_project
 
 
@@ -456,3 +457,86 @@ def test_flash_then_se2_project_end_to_end():
     want = core_attn.relative_attention_quadratic(enc, q, k, v, pose, pose)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=5e-3, rtol=5e-3)
+
+
+# ---------------------------------------------------------------------------
+# Layer-stacked, feature-major decode cache: (L, B, H, c, S)
+# ---------------------------------------------------------------------------
+
+#: as tests/test_decode.py's DECODE_TOL: every path reads the same cache
+#: values; bf16 is looser because the generic fallback rounds its output
+#: to the cache dtype
+STACKED_TOL = {"float32": dict(atol=2e-5, rtol=2e-4),
+               "bfloat16": dict(atol=8e-3, rtol=8e-3),
+               "int8": dict(atol=2e-4, rtol=2e-3)}
+
+
+def _stacked_case(c, cache_dtype, seed, *, layers=3, layer=1):
+    """A stacked cache built from row-major (L, B, Hkv, S, c) rows, with
+    rows past each slot's cursor poisoned with NaN (in the scales of an
+    int8 cache); returns the kernels' operands and the oracle's clean
+    layer ``layer`` in (B, Hkv, S, c)."""
+    rng = np.random.default_rng(seed)
+    b, hq, hkv, sq, s = 3, 4, 2, 4, 64
+    kvl = np.asarray([s - 5, 17, 33], np.int32)       # ragged cursors
+    q = jnp.asarray(rng.normal(size=(b, hq, sq, c)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(layers, b, hkv, s, c)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(layers, b, hkv, s, c)), jnp.float32)
+    kw = dict(
+        q_times=jnp.full((b, sq), 6, jnp.int32),
+        k_times=jnp.asarray(np.sort(rng.integers(0, 8, size=(b, s)), -1),
+                            jnp.int32),
+        q_segment_ids=jnp.asarray(rng.integers(0, 2, size=(b, sq)),
+                                  jnp.int32),
+        k_segment_ids=jnp.asarray(rng.integers(-1, 2, size=(b, s)),
+                                  jnp.int32))
+    dead = jnp.asarray(np.arange(s)[None, :] >= kvl[:, None])   # (B, S)
+    dead_rows = dead[None, :, None, :, None]
+    k_scale = v_scale = None
+    if cache_dtype == "int8":
+        k, k_scale = quantize_kv(k)
+        v, v_scale = quantize_kv(v)
+        k_oracle = dequantize_kv(k[layer], k_scale[layer])
+        v_oracle = dequantize_kv(v[layer], v_scale[layer])
+        k_scale = jnp.where(dead[None, :, None], jnp.nan, k_scale)
+        v_scale = jnp.where(dead[None, :, None], jnp.nan, v_scale)
+    else:
+        k = k.astype(cache_dtype)
+        v = v.astype(cache_dtype)
+        k_oracle = k[layer].astype(jnp.float32)
+        v_oracle = v[layer].astype(jnp.float32)
+        k = jnp.where(dead_rows, jnp.nan, k).astype(cache_dtype)
+        v = jnp.where(dead_rows, jnp.nan, v).astype(cache_dtype)
+    k, v = jnp.swapaxes(k, -1, -2), jnp.swapaxes(v, -1, -2)    # (L,B,H,c,S)
+    stacked = dict(kv_length=jnp.asarray(kvl), k_scale=k_scale,
+                   v_scale=v_scale, layer=layer, **kw)
+    return q, k, v, stacked, k_oracle, v_oracle, kw
+
+
+@pytest.mark.parametrize("cache_dtype", sorted(STACKED_TOL))
+@pytest.mark.parametrize("c", [24, 200])
+def test_stacked_feature_major_decode_parity(c, cache_dtype):
+    """flash_decode (interpret) == decode_ragged_xla == the ref fallback
+    on a feature-major stacked cache, == ref.mha_reference over the
+    row-major layer it was built from: row widths of both serving
+    encodings, ragged cursors, times, segments, GQA, and NaN rows past
+    every cursor."""
+    q, k, v, stacked, k_oracle, v_oracle, kw = _stacked_case(
+        c, cache_dtype, seed=c)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.mha_reference(
+            q, k_oracle, v_oracle, causal=True,
+            kv_length=stacked["kv_length"], **kw), np.float32)
+    got = {
+        "flash_decode": ops.decode_attention(
+            q, k, v, impl="flash_decode", block_k=16, num_splits=2,
+            interpret=True, **stacked),
+        "xla": ops.decode_attention(q, k, v, impl="xla", block_k=16,
+                                    **stacked),
+        "ref": ops.decode_attention(q, k, v, impl="ref", **stacked),
+    }
+    for name, g in got.items():
+        g = np.asarray(g, np.float32)
+        assert np.isfinite(g).all(), name
+        np.testing.assert_allclose(g, want, **STACKED_TOL[cache_dtype],
+                                   err_msg=f"{name} c={c} {cache_dtype}")
