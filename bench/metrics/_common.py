@@ -37,3 +37,30 @@ def roofline(ctx, flops_key, bytes_key, patterns):
     if t is None or not w.get(flops_key):
         return None
     return 100.0 * least_time(w[flops_key], w[bytes_key], ctx["peak"])[0] / t
+
+
+def scope_seconds(ctx, name):
+    """Device seconds of the traced window under the named scope ``name``
+    (``jax.named_scope``), summed over devices; None without a trace or
+    when no op carries the scope."""
+    tr = ctx.get("trace")
+    if not tr:
+        return None
+    return tr["scope_seconds"].get(name)
+
+
+def scope_share(ctx, name):
+    """``scope_seconds`` over the devices' busy time, in %."""
+    t, tr = scope_seconds(ctx, name), ctx.get("trace")
+    if t is None or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * t / (tr["busy_s"] * tr["devices"])
+
+
+def spans(ctx, name):
+    """(start_s, end_s) of the traced window's host spans called ``name``,
+    clipped to the window, in order; [] without a trace."""
+    tr = ctx.get("trace")
+    if not tr:
+        return []
+    return [(s, e) for n, s, e in tr["spans"] if n == name]

@@ -3,15 +3,45 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``BENCHMARK.json``'s ``workloads``: it names a
-configuration (``bench/configs/<config>.json``: the model, its cache and
-the server's slots) and a traffic mix (``bench/traffic/<traffic>.json``:
-scene caps, request shape, arrivals), and its correctness limits are in
-``bench/limits/<cell>.json``. The traffic's ``kind`` picks the runner
-(``serve``). Set-up (weights, inputs, compilation and warm-up) is timed
-apart from the measured window.
+configuration (the file its ``configs`` entry gives, under
+``bench/configs/``) and a traffic mix (``bench/traffic/<traffic>.json``),
+and its correctness limits are in ``bench/limits/<cell>.json``. Set-up
+(weights, inputs, compilation and warm-up) is timed apart from the
+measured window.
+
+The traffic's ``kind`` names its runner, the module ``bench/<kind>.py``
+(``serve``: ``SimServer`` on the agent-sim configurations). A runner is
+the only part that knows the program, so a configuration the runners here
+cannot drive joins with new files alone: its runner, configuration,
+traffic, limits and readers.
+
+The runner contract: ``run(cell)`` drives the program for
+``cell.window_seconds()`` inside ``with cell.profile() as prof``
+(``prof.mark_start()`` and ``prof.mark_end()`` around the window), then
+checks what the window produced, and returns a dict with
+
+* ``setup_s``: seconds of set-up before the window;
+* ``metrics``: ``{name: value}``, every end-to-end metric that lists the
+  cell (``setup_s`` aside);
+* ``layer``: work counted from shapes in the traced window, for the
+  per-layer readers (``{}`` untraced);
+* ``readings``: ``{name: value}`` of the correctness numbers, each held to
+  ``bench/limits/<cell>.json``, and ``tokens_compared`` (> 0);
+* ``control``: the control's readings in the same form, or None;
+* ``attempted``, ``failed``: requests due in the window, and those of them
+  that did not finish sound;
+* ``memory_peak_bytes``: ``cell.memory_peak()``, read before the
+  reference runs;
+* ``info``: anything else, printed to standard error;
+* ``chips`` (optional, default 1): the devices the run used.
+
+A runner may also define ``SPANS``, a tuple of host span-name prefixes
+(``serve``: ``("sim_server.",)``): a traced run keeps those spans beside
+the benchmark's own ``bench.*`` ones (``bench/trace.py``).
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end metrics;
-with ``--trace 1`` a profiler trace of the window is reduced by the
+with ``--trace 1`` a profiler trace of the window is reduced (device time
+by op and by named scope, the kept host spans, idle gaps) and read by the
 per-layer readers in ``bench/metrics/<metric>.py``.
 
 The last line of standard output is one JSON object: ``correct``,
@@ -23,6 +53,7 @@ the cell asks for, or on a device kind missing from ``bench/peaks.py``.
 from __future__ import annotations
 
 import argparse
+import importlib
 import importlib.util
 import json
 import math
@@ -57,8 +88,12 @@ class Cell:
                  trace, device=None, peak=None, control=False):
         self.name, self.config, self.traffic = name, config, traffic
         self.limits = limits
-        self.model, self.grid = config["model"], config["action_grid"]
-        self.cache_dtype = config["cache_dtype"]
+        # None where the configuration has no such key: a catalog model's
+        # sizes sit at the file's top level, and only the agent-sim
+        # configurations have an action grid and a cache dtype
+        self.model = config.get("model")
+        self.grid = config.get("action_grid")
+        self.cache_dtype = config.get("cache_dtype")
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.device, self.peak, self.control = device, peak, control
         self.trace_result = None
@@ -172,7 +207,8 @@ class _Profile:
         jax.profiler.stop_trace()
         try:
             if exc[0] is None:
-                ops, spans = trace.load(self.dir)
+                ops, spans = trace.load(
+                    self.dir, getattr(runner(self.cell), "SPANS", ()))
                 win = [(s, e) for n, s, e in spans if n == "bench.window"]
                 if not win:
                     raise RuntimeError("no bench.window span in the trace")
@@ -260,12 +296,25 @@ def enable_compile_cache():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def execute(cell):
-    """Run the cell's runner; returns its outcome dict."""
-    from bench import serve
+def runner(cell):
+    """The module ``bench.<kind>`` for the cell's traffic ``kind``."""
+    kind = cell.traffic["kind"]
+    if not (isinstance(kind, str) and kind.isidentifier()):
+        raise SystemExit(f"traffic kind {kind!r} is not a plain identifier")
+    try:
+        return importlib.import_module("bench." + kind)
+    except ModuleNotFoundError as e:
+        if e.name != "bench." + kind:
+            raise
+        raise SystemExit(f"no runner for traffic kind {kind!r}: module "
+                         f"bench.{kind} (bench/{kind}.py) is missing"
+                         ) from None
 
-    runners = {"serve": serve.run}
-    return runners[cell.traffic["kind"]](cell)
+
+def execute(cell):
+    """Run the cell's runner; returns its outcome dict (the contract in
+    this module's docstring)."""
+    return runner(cell).run(cell)
 
 
 def main(argv=None):

@@ -20,6 +20,13 @@ server's in-flight lane table), which gives the rollout steps delivered in
 the window, the gap between a lane's consecutive actions, and each lane's
 first action.
 
+The configuration's ``drain_lag`` (default 1) is how many ticks the server
+keeps dispatched ahead of the one whose actions the host waits for, so a
+host stall shorter than that leaves the chip fed. The window starts with
+nothing outstanding and, when its time is up, dispatches nothing more and
+waits for every tick it sent, recording each tick's actions as they land:
+the window counts all the work it dispatched, over all the time it took.
+
 After the window, lanes still queued in a saturated cell are cancelled (they
 were never attempted); every other lane is driven to its end. Then the
 server is freed and a sample of finished lanes, drawn from the seed, is
@@ -41,6 +48,9 @@ from bench.weights import make_params
 
 #: longest a run waits after the window for lanes to finish
 DRAIN_LIMIT_S = 60.0
+#: the server's host spans (``SimServer.tick`` and its children), kept in
+#: a traced run's reduction
+SPANS = ("sim_server.",)
 
 
 def build(cell):
@@ -61,7 +71,8 @@ def build(cell):
     cell.check_params(params, model)
     reg = obs.Registry()
     srv = SimServer(model, params, scen, num_slots=cell.config["serve_slots"],
-                    cache_dtype=cell.cache_dtype, registry=reg)
+                    cache_dtype=cell.cache_dtype,
+                    drain_lag=cell.config.get("drain_lag", 1), registry=reg)
     pool = make_scenes(cell.seed, shape, cell.grid, tr["scene_pool"])
     return srv, reg, pool
 
@@ -305,6 +316,16 @@ def drive(cell, srv, traffic, lanes, *, until, annotate, tick_log=None,
             return now
 
 
+def settle(srv, lanes):
+    """Wait for every tick the server has dispatched, landing each one's
+    actions on the host in turn; returns the time after the wait."""
+    while srv._pending:
+        srv._drain(len(srv._pending) - 1)
+        lanes.record(time.perf_counter())
+    jax.block_until_ready((srv.cache, srv.state))
+    return time.perf_counter()
+
+
 def run(cell):
     t_setup = time.perf_counter()
     srv, reg, pool = build(cell)
@@ -325,14 +346,14 @@ def run(cell):
     seconds = cell.window_seconds()
     tick_log = [] if cell.trace else None
     with cell.profile() as prof, Watch() as watch:
-        jax.block_until_ready(srv.cache)
+        settle(srv, lanes)
         t0 = prof.mark_start()
         admitted0 = srv.admitted
         admit0 = _hist(reg, "sim_server.admit.seconds")
-        t_end = drive(cell, srv, traffic, lanes, annotate=cell.trace,
-                      tick_log=tick_log, watch=watch,
-                      until=lambda now: now - t0 >= seconds)
-        jax.block_until_ready(srv.cache)
+        drive(cell, srv, traffic, lanes, annotate=cell.trace,
+              tick_log=tick_log, watch=watch,
+              until=lambda now: now - t0 >= seconds)
+        t_end = settle(srv, lanes)
         admit1 = _hist(reg, "sim_server.admit.seconds")
         prof.mark_end()
     window = t_end - t0

@@ -94,3 +94,24 @@ def test_the_stagger_spreads_the_slots_over_a_lane():
     serve.drive(cell, srv, traffic, lanes, annotate=False,
                 until=lambda now: srv.ticks - ticks0 >= 12)
     assert srv.admitted == 9
+
+
+def test_the_window_waits_for_every_tick_dispatched_ahead():
+    """With ticks dispatched ahead of the drain, closing the window lands
+    every outstanding tick's actions, one tick at a time, so no lane's
+    actions arrive in a lump."""
+    cell = tiny_cell("se2-wosac-serve", slots=3, num_steps=12)
+    cell.config["drain_lag"] = 6
+    srv, _, pool = serve.build(cell)
+    assert srv.drain_lag == 6
+    lanes = serve.Lanes(srv)
+    traffic = serve.Traffic(cell, srv, lanes, pool)
+    traffic.prime(0.0)
+    serve.drive(cell, srv, traffic, lanes, annotate=False,
+                until=lambda now: srv.ticks >= 15)
+    assert len(srv._pending) == 6
+    landed = len(lanes.arrived)
+    t = serve.settle(srv, lanes)
+    assert not srv._pending
+    new = lanes.arrived[landed:]
+    assert len(new) >= 6 and all(n == 1 and s <= t for s, n in new), new

@@ -57,18 +57,23 @@ def test_reduce_clips_to_the_window_and_averages_devices():
     assert r["op_seconds"]["_decode_kernel"] == pytest.approx(1.0)
 
 
-def test_a_recorded_trace_loads(tmp_path):
-    """A profiler trace recorded on a TPU v5e: three ticks of a one-slot
-    server at the registered scene shape, each in a bench.tick span."""
+def _unpack(tmp_path, name):
+    """A gzipped trace under ``data/`` laid out as the profiler writes it."""
     import gzip
     import shutil
 
     prof = tmp_path / "plugins" / "profile" / "run"
     prof.mkdir(parents=True)
-    with gzip.open(os.path.join(DATA, "v5e_ticks.xplane.pb.gz")) as src, \
+    with gzip.open(os.path.join(DATA, name)) as src, \
             open(prof / "ticks.xplane.pb", "wb") as dst:
         shutil.copyfileobj(src, dst)
-    ops, spans = trace.load(str(tmp_path))
+    return str(tmp_path)
+
+
+def test_a_recorded_trace_loads(tmp_path):
+    """A profiler trace recorded on a TPU v5e: three ticks of a one-slot
+    server at the registered scene shape, each in a bench.tick span."""
+    ops, spans = trace.load(_unpack(tmp_path, "v5e_ticks.xplane.pb.gz"))
     assert ops and all(evs for evs in ops.values())
     ticks = [s for s in spans if s[0] == "bench.tick"]
     assert len(ticks) >= 3
@@ -76,6 +81,173 @@ def test_a_recorded_trace_loads(tmp_path):
     r = trace.reduce(ops, spans, lo, hi)
     assert 0 < r["busy_s"] <= r["window_s"]
     assert any("tpu_custom_call" in n for n in r["op_seconds"])
+
+
+def test_the_recorded_trace_carries_scopes():
+    """The older recording's tick ops carry their scope path; an op named
+    after an argument (``cache['k']:``) carries none."""
+    import gzip
+
+    with gzip.open(os.path.join(DATA, "v5e_ticks.xplane.pb.gz")) as f:
+        tf_ops = trace.op_tf_ops(f.read())
+    paths = [trace.scope_path(t) for evs in tf_ops.values() for _, t in evs
+             if t]
+    scoped = [p for p in paths if p]
+    assert len(scoped) > 300
+    assert all(p[:2] == ("jit(_tick_impl)", "sim_server.tick")
+               for p in scoped)
+    assert trace.scope_path("cache['k']:") == ()
+
+
+def test_scopes_and_program_spans_of_a_recorded_serve_trace(tmp_path):
+    """A profiler trace recorded on a TPU v5e at the scene shape of the
+    cells: ticks of a one-slot sim-se2-fourier server, one admitting a
+    lane, each in a bench.tick span (``record_serve_trace.py``)."""
+    ops, spans = trace.load(
+        _unpack(tmp_path, "v5e_serve.xplane.pb.gz"), ("sim_server.",))
+    names = {n for n, _, _ in spans}
+    assert {"bench.tick", "sim_server.tick", "sim_server.drain",
+            "sim_server.admit"} <= names
+    ticks = [s for s in spans if s[0] == "bench.tick"]
+    r = trace.reduce(ops, spans, ticks[0][1], ticks[-1][2])
+    sec = r["scope_seconds"]
+    for scope in ("agent_sim.cache_write", "agent_sim.se2_transform",
+                  "agent_sim.decode_attention", "sim_server.tick"):
+        assert sec.get(scope, 0) > 0, (scope, sorted(sec))
+    # an op counts once under each scope it sits in, never twice
+    assert max(sec.values()) <= sum(r["op_seconds"].values()) + 1e-12
+    assert {n for n, _, _ in r["spans"]} == names
+    # without the runner's prefixes only the benchmark's spans are kept
+    _, own = trace.load(str(tmp_path))
+    assert {n for n, _, _ in own} == {n for n in names
+                                      if n.startswith("bench.")}
+
+
+SCOPES = {"fusion.1": ("jit(tick)", "model", "model.attn"),
+          "_decode_kernel": ("jit(tick)", "model", "model.attn"),
+          "copy": ("jit(tick)", "model")}
+SCOPED = [op + (SCOPES[op[0]],) for op in OPS]
+
+
+def test_scope_path_drops_the_primitive_and_merges_fused_names():
+    assert trace.scope_path("jit(f)/a/b/dot_general:") == ("jit(f)", "a",
+                                                           "b")
+    assert trace.scope_path("jit(f)/a/x;jit(f)/c/y:") == ("jit(f)", "a",
+                                                          "c")
+    assert trace.scope_path("add:") == ()
+    assert trace.scope_path("") == ()
+
+
+def test_scope_seconds_count_nested_scopes_and_ops_without_one():
+    ops = SCOPED + [("unnamed", 8.0, 9.0, ()), ("bare", 9.0, 9.5)]
+    got = dict(trace.time_by_scope(ops))
+    assert got["model.attn"] == pytest.approx(2.0 + 1.5)
+    assert got["model"] == pytest.approx(2.0 + 1.5 + 0.5)
+    assert got["jit(tick)"] == got["model"]
+    assert got[trace.NO_SCOPE] == pytest.approx(1.5)
+
+
+def test_reduce_clips_scopes_and_spans_to_the_window():
+    spans = SPANS + [("sim_server.tick", 0.5, 2.5)]
+    r = trace.reduce({"/device:TPU:0": SCOPED}, spans, 1.0, 6.0)
+    # inside the window: the kernel [1, 2] and fusion.1 [4, 5]
+    assert r["scope_seconds"]["model.attn"] == pytest.approx(1.0 + 1.0)
+    assert "copy" not in r["op_seconds"]
+    assert r["devices"] == 1
+    assert r["spans"] == [("bench.window", 1.0, 6.0),
+                          ("bench.tick", 1.0, 3.0),
+                          ("sim_server.tick", 1.0, 2.5),
+                          ("bench.tick", 3.5, 6.0),
+                          ("bench.submit", 5.2, 6.0)]
+    # gap [2, 4], mid 3.0: both ticks have ended, so the window; gap
+    # [5, 6], mid 5.5: the submit
+    gaps = dict(r["idle_gaps"])
+    assert gaps == pytest.approx({"bench.window": 2.0, "bench.submit": 1.0})
+
+
+def test_idle_gaps_charge_the_innermost_program_span():
+    spans = SPANS + [("sim_server.tick", 3.6, 7.9),
+                     ("sim_server.drain", 5.5, 7.0)]
+    gaps = dict(trace.idle_gaps(OPS, spans, 0.0, 10.0))
+    # [5, 7] mid 6.0: bench.tick, sim_server.tick and its drain open; the
+    # drain started last
+    assert gaps["sim_server.drain"] == pytest.approx(2.0)
+    assert "bench.tick" not in gaps
+    assert sum(gaps.values()) == pytest.approx(10.0 - 3.5)
+
+
+def _pb(*fields):
+    """A protobuf message from (field number, value) pairs: an int as a
+    varint, a str or bytes as a length-delimited field."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n >> 7 else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+    out = b""
+    for num, v in fields:
+        if isinstance(v, int):
+            out += varint(num << 3) + varint(v)
+        else:
+            v = v.encode() if isinstance(v, str) else v
+            out += varint(num << 3 | 2) + varint(len(v)) + v
+    return out
+
+
+def test_ops_of_one_name_in_two_programs_keep_their_own_scopes(tmp_path):
+    """Two programs hold the same HLO line, so two event metadata entries
+    share one name: each event is charged to the scope of the metadata it
+    points to, not to whichever entry came last."""
+    op = "%copy.1 = f32[8]{0} copy(f32[8]{0} %p.1)"
+
+    def meta(i, tf_op):      # map entry: XEventMetadata with a tf_op stat
+        return _pb((1, i), (2, _pb((1, i), (2, op),
+                                   (5, _pb((1, 7), (5, tf_op))))))
+
+    def event(mid, start_ps, dur_ps):
+        return _pb((1, mid), (2, start_ps), (3, dur_ps))
+
+    line = _pb((1, 1), (2, trace.OPS_LINE), (3, 0),
+               (4, event(1, 0, 10**12)), (4, event(2, 2 * 10**12, 10**12 // 2)),
+               (4, event(1, 3 * 10**12, 10**12 // 4)))
+    plane = _pb((1, 1), (2, "/device:TPU:0"), (3, line),
+                (4, meta(1, "jit(tick)/agent_sim.cache_write/copy:")),
+                (4, meta(2, "jit(admit)/sim_server.admit/copy:")),
+                (5, _pb((1, 7), (2, _pb((1, 7), (2, trace.SCOPE_STAT))))))
+    prof = tmp_path / "plugins" / "profile" / "run"
+    prof.mkdir(parents=True)
+    (prof / "two.xplane.pb").write_bytes(_pb((1, plane)))
+    ops, spans = trace.load(str(tmp_path))
+    tick = ("jit(tick)", "agent_sim.cache_write")
+    admit = ("jit(admit)", "sim_server.admit")
+    assert [(n, s, e, sc) for n, s, e, sc in ops["/device:TPU:0"]] == [
+        (op, 0.0, pytest.approx(1.0), tick),
+        (op, pytest.approx(2.0), pytest.approx(2.5), admit),
+        (op, pytest.approx(3.0), pytest.approx(3.25), tick)]
+    assert spans == []
+    r = trace.reduce(ops, spans, 0.0, 4.0)
+    assert r["op_seconds"] == {op: pytest.approx(1.75)}
+    assert r["scope_seconds"]["agent_sim.cache_write"] == pytest.approx(1.25)
+    assert r["scope_seconds"]["sim_server.admit"] == pytest.approx(0.5)
+
+
+def test_load_keeps_the_runner_spans_and_drops_the_rest(tmp_path):
+    """A trace recorded here (no device plane): the host spans ``load``
+    keeps are the benchmark's and the declared prefixes'."""
+    import jax
+
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.window"):
+            for name in ("toy.step", "toy.step.sample", "other.step"):
+                with jax.profiler.TraceAnnotation(name):
+                    jax.block_until_ready(jax.numpy.ones(3) + 1)
+    ops, spans = trace.load(str(tmp_path), ("toy.",))
+    assert sorted(n for n, _, _ in spans) == ["bench.window", "toy.step",
+                                              "toy.step.sample"]
+    assert all(s <= e for _, s, e in spans)
+    assert not ops
 
 
 # -- work counts ------------------------------------------------------------------
