@@ -1,8 +1,11 @@
 """Serve a small LM with continuous batching (per-slot cache cursors).
 
-Run:  PYTHONPATH=src python examples/serve_lm.py --arch phi4-mini-3.8b
-(uses the reduced same-family config so it runs on CPU; drop --reduced on
-real hardware).
+Run:  PYTHONPATH=src python examples/serve_lm.py
+(uses the reduced same-family config so it runs on CPU; ``--full`` on real
+hardware). The default, DeepSeek-V2-Lite, takes the latent-cache tick:
+prompts enter in chunks beside decoding slots, tokens are sampled on the
+device, and ticks are dispatched ahead of the host. ``--arch`` names any
+registered LM (``phi4-mini-3.8b`` takes the one-token-a-tick path).
 """
 import argparse
 import logging
@@ -20,7 +23,7 @@ from repro.runtime.server import Request, Server
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="phi4-mini-3.8b")
+    ap.add_argument("--arch", default="deepseek-v2-lite-16b")
     ap.add_argument("--full", action="store_true",
                     help="full config (needs a real accelerator)")
     ap.add_argument("--requests", type=int, default=6)
@@ -34,7 +37,8 @@ def main():
         cfg = cfg.reduced(dtype="float32")
     model = build_model(cfg)
     params = nnm.init_params(model.specs(), jax.random.key(0))
-    srv = Server(model, params, num_slots=args.slots, max_len=128)
+    srv = Server(model, params, num_slots=args.slots, max_len=128,
+                 prefill_chunk=8, drain_lag=2)
 
     rng = np.random.default_rng(0)
     t0 = time.time()

@@ -1,17 +1,20 @@
-"""The 10 assigned architectures, exactly as specified in the task brief.
+"""The 10 registered LM architectures and the agent-sim architectures.
 
-Sources are noted per config; where the one-line brief conflicts with the
-published model card we follow the brief and note the deviation (see
-DESIGN.md "Assigned architectures" for the reconciliation).
+Sources are noted per config; where a one-line description conflicts with
+the published model card we follow the card and note the deviation in the
+config's ``notes``.
 """
 from __future__ import annotations
 
-from repro.configs.base import (MLAConfig, ModelConfig, MoEConfig, SimArch,
-                                SSMConfig, register, register_sim)
+from repro.configs.base import (MLAConfig, ModelConfig, MoEConfig,
+                                RopeScaling, SimArch, SSMConfig, register,
+                                register_sim)
 
 # --- deepseek-v2-lite-16b [arXiv:2405.04434; hf] ---------------------------
 # 27L d=2048, 16 heads, MLA kv_lora=512, MoE: 64 routed top-6 + 2 shared,
-# expert_ff=1408, first layer dense (dense_ff=10944).
+# expert_ff=1408, first layer dense (dense_ff=10944); YaRN rope scaling
+# (factor 40 over 4096), softmax routing without top-k renormalisation,
+# rms_norm_eps 1e-6 (every RMSNorm's default).
 register(ModelConfig(
     name="deepseek-v2-lite-16b", family="moe",
     num_layers=27, d_model=2048, num_q_heads=16, num_kv_heads=16,
@@ -19,11 +22,18 @@ register(ModelConfig(
     attention_kind="mla",
     mla=MLAConfig(kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
                   v_head_dim=128),
+    rope_scaling=RopeScaling(factor=40.0, original_max_position=4096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                             mscale_all_dim=0.707),
+    max_position=163840,
     moe=MoEConfig(num_experts=64, top_k=6, expert_ff=1408, num_shared=2,
-                  first_k_dense=1, dense_ff=10944),
+                  first_k_dense=1, dense_ff=10944, norm_topk_prob=False),
     activation="silu", norm="rms",
-    notes="MLA + fine-grained MoE; brief lists '160 routed' which matches "
-          "deepseek-v2 (236B), not -lite; we follow the hf card (64 routed).",
+    notes="MLA + fine-grained MoE; a description listing '160 routed' "
+          "matches deepseek-v2 (236B), not -lite; we follow the hf card "
+          "(64 routed). The rope channels of q and k_rope pair split-half "
+          "(feature i with i + 32); the hf code de-interleaves them first, "
+          "a fixed permutation of those 64 weight columns.",
 ))
 
 # --- kimi-k2-1t-a32b [arXiv: Kimi K2 tech report; paper-table] --------------
@@ -112,7 +122,7 @@ register(ModelConfig(
     ssm=SSMConfig(kind="mamba", state_size=16, d_inner=3200, chunk=128),
     activation="silu", norm="rms",
     long_context_ok=True,
-    notes="Parallel attn+SSM heads; meta-tokens omitted (see DESIGN.md). "
+    notes="Parallel attn+SSM heads; meta-tokens omitted. "
           "SWA + SSM make long_500k decode sub-quadratic.",
 ))
 
@@ -140,8 +150,7 @@ register(ModelConfig(
     ssm=SSMConfig(kind="rwkv6", head_dim=64, chunk=16),
     norm="layer",
     long_context_ok=True,
-    notes="Paper's attention technique inapplicable (attention-free); see "
-          "DESIGN.md Arch-applicability.",
+    notes="Paper's attention technique inapplicable (attention-free).",
 ))
 
 # --- agent-sim architectures (paper Table I rows) ---------------------------

@@ -17,6 +17,18 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071), in the fields of a Hugging Face
+    ``rope_scaling`` entry of type "yarn"."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
@@ -26,6 +38,9 @@ class MoEConfig:
     dense_ff: Optional[int] = None  # d_ff of those dense layers
     capacity_factor: float = 1.25
     aux_weight: float = 0.01
+    #: True: the top-k weights are renormalised to sum to 1; False
+    #: (deepseek-v2): softmax over all experts, taken at the top k
+    norm_topk_prob: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +69,7 @@ class ModelConfig:
     pos_enc: str = "rope1d"                 # rope1d | absolute | sinusoidal | none
     rope_base: float = 10000.0
     rope_fraction: float = 1.0
+    rope_scaling: Optional[RopeScaling] = None
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
     query_scale: Optional[float] = None

@@ -41,7 +41,7 @@ dimension ``pose_dim`` (1 for R, 2 for R^2, 3 for SE(2)).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -128,6 +128,39 @@ def rope_frequencies(num_freqs: int, base: float = 10000.0,
     return max_freq * (base ** (-2.0 * i / (2.0 * num_freqs)))
 
 
+def yarn_ramp_bounds(dim: int, base: float, original_max_position: int,
+                     beta_fast: float, beta_slow: float) -> Tuple[int, int]:
+    """YaRN's correction range (arXiv:2309.00071, Sec. 3.2): the pair
+    indices at which a frequency makes ``beta_fast`` and ``beta_slow``
+    rotations over the original context, floored and ceiled and clamped to
+    [0, dim - 1], as DeepSeek-V2's ``yarn_find_correction_range``."""
+    def index(rotations):
+        return (dim * np.log(original_max_position / (rotations * 2 * np.pi))
+                / (2 * np.log(base)))
+    lo = int(np.floor(index(beta_fast)))
+    hi = int(np.ceil(index(beta_slow)))
+    return max(lo, 0), min(hi, dim - 1)
+
+
+def yarn_frequencies(dim: int, base: float, factor: float,
+                     original_max_position: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN's blended rope frequencies, ``dim // 2`` of them: pair indices
+    at or below the ramp's low bound keep the original frequency, those at
+    or above its high bound take it divided by ``factor``, and a linear
+    ramp joins them."""
+    orig = rope_frequencies(dim // 2, base)
+    lo, hi = yarn_ramp_bounds(dim, base, original_max_position, beta_fast,
+                              beta_slow)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return orig / factor * ramp + orig * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, ``0.1 m ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
 @dataclasses.dataclass(frozen=True)
 class Rope1D(GroupEncoding):
     """Rotary embeddings for G = R (token index or any scalar coordinate).
@@ -141,15 +174,20 @@ class Rope1D(GroupEncoding):
     max_freq: float = 1.0
     pose_dim: int = 1
     name: str = "rope1d"
+    #: YaRN scaling as (factor, original_max_position, beta_fast,
+    #: beta_slow): the frequencies of :func:`yarn_frequencies`
+    yarn: Optional[Tuple[float, int, float, float]] = None
 
     def __post_init__(self):
         if self.head_dim % 2 != 0:
             raise ValueError(f"rope1d head_dim must be even, got {self.head_dim}")
 
     def _freqs(self, dtype):
-        return jnp.asarray(
-            rope_frequencies(self.head_dim // 2, self.base, self.max_freq),
-            dtype=dtype)
+        if self.yarn is not None:
+            f = yarn_frequencies(self.head_dim, self.base, *self.yarn)
+        else:
+            f = rope_frequencies(self.head_dim // 2, self.base, self.max_freq)
+        return jnp.asarray(f, dtype=dtype)
 
     def _cos_sin(self, pose):
         pos = pose[..., 0]

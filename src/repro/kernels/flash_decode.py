@@ -39,9 +39,22 @@ This kernel is specialized for the decode shape:
   working set of a tick shrinks 4x (2x for bf16) while all arithmetic
   stays float32.
 
+* **Grouped heads in one grid step** — with ``group = Hq / Hkv > 1``
+  (GQA, MQA, and MLA's latent cache: 16 query heads over one latent "kv
+  head"), a kv head's query heads are folded into the query rows of one
+  grid step, so each key block is read from HBM once per kv head, not
+  once per query head. The grid is ``(B, Hkv, num_splits,
+  blocks_per_split)``; ``group == 1`` is the unfolded program.
+* **Values inside the keys** — ``v=None`` with ``v_dim`` marks a latent
+  cache whose value rows are the first ``v_dim`` features of the key rows
+  (MLA's absorbed decode: keys are the 576-wide latent-plus-rope rows,
+  values their 512 latent features); the kernel slices them out of the
+  key tile already in VMEM and reads no second operand. It is named
+  ``flash_decode_latent`` in the compiled program.
+
 Masking supports the decode feature set the model actually uses:
-block-causal attention over explicit per-token times, segment ids, GQA
-(via ``h // group`` index maps), and the ragged ``kv_length`` bound.
+block-causal attention over explicit per-token times, segment ids, grouped
+query heads, and the ragged ``kv_length`` bound.
 Softcap / sliding windows are deliberately out of scope — no decode
 path uses them; fall back to the generic kernel if that changes.
 
@@ -119,19 +132,25 @@ def _column(row):
 
 
 def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
-                   blocks_per_split: int, num_k_blocks: int, group: int,
+                   blocks_per_split: int, num_k_blocks: int,
                    use_segments: bool, use_times: bool,
-                   quant_k: bool, quant_v: bool, layered: bool):
+                   quant_k: bool, quant_v: bool, layered: bool,
+                   v_in_k: Optional[int]):
     """One grid step: fold one key block into this split's (m, l, acc).
 
-    Grid: (B, Hq, num_splits, blocks_per_split); the last dimension is
-    sequential so the online-softmax scratch carries across it; the split
-    dimension is parallel. Outputs are per-split partials, combined by
-    :func:`_combine_splits`.
+    Grid: (B, Hkv, num_splits, blocks_per_split), a kv head's query heads
+    folded into the query rows; the last dimension is sequential so the
+    online-softmax scratch carries across it; the split dimension is
+    parallel. Outputs are per-split partials, combined by
+    :func:`_combine_splits`. ``v_in_k``: the values are the first
+    ``v_in_k`` features of the key tile (no value operand).
     """
-    (q_seg_ref, k_seg_ref, q_time_ref, k_time_ref,
-     q_ref, k_ref, v_ref) = refs[:7]
-    i = 7
+    (q_seg_ref, k_seg_ref, q_time_ref, k_time_ref, q_ref, k_ref) = refs[:6]
+    i = 6
+    v_ref = None
+    if v_in_k is None:
+        v_ref = refs[i]
+        i += 1
     k_scale_ref = v_scale_ref = None
     if quant_k:
         k_scale_ref = refs[i]
@@ -143,7 +162,7 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
     acc_s, m_s, l_s = refs[i + 3:]
 
     b = pl.program_id(0)
-    hk = pl.ds(pl.program_id(1) // group, 1)     # this program's kv head
+    hk = pl.ds(pl.program_id(1), 1)              # this program's kv head
     split = pl.program_id(2)
     ik = pl.program_id(3)
     jk = split * blocks_per_split + ik          # global key-block index
@@ -178,9 +197,14 @@ def _decode_kernel(kvl_ref, *refs, scale: float, block_k: int,
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (bq, d)
         k = k_ref[kv_idx].astype(jnp.float32)
-        v = v_ref[kv_idx].astype(jnp.float32)
         if quant_k:
             k = k * row_scale(k_scale_ref)
+        if v_in_k is None:
+            v = v_ref[kv_idx].astype(jnp.float32)
+        elif layered:
+            v = k[:v_in_k, :]
+        else:
+            v = k[:, :v_in_k]
         if quant_v:
             v = v * row_scale(v_scale_ref)
         s = jax.lax.dot_general(
@@ -248,6 +272,20 @@ def _combine_splits(o_p, m_p, l_p, out_dtype):
     return out.astype(out_dtype)
 
 
+def _fold_heads(q, hkv, q_segment_ids, q_times):
+    """Fold each kv head's ``group`` query heads into its query rows: q
+    (B, Hq, Sq, D) -> (B, Hkv, group * Sq, D), row ``g * Sq + s`` holding
+    query ``s`` of head ``kv * group + g``; the per-query rows are tiled to
+    match. Identity when ``group == 1``."""
+    b, hq, sq, d = q.shape
+    group = hq // hkv
+    if group == 1:
+        return q, q_segment_ids, q_times
+    tile = (lambda x: None if x is None else jnp.tile(x, (1, group)))
+    return (q.reshape(b, hkv, group * sq, d), tile(q_segment_ids),
+            tile(q_times))
+
+
 def flash_decode_fwd(q, k, v, kv_length, *,
                      k_scale=None, v_scale=None,
                      q_segment_ids=None, k_segment_ids=None,
@@ -256,14 +294,17 @@ def flash_decode_fwd(q, k, v, kv_length, *,
                      block_k: int = 128,
                      num_splits: Optional[int] = None,
                      interpret: bool = False,
-                     layer: Optional[int] = None):
+                     layer: Optional[int] = None,
+                     v_dim: Optional[int] = None):
     """Raw kernel invocation. Requires aligned shapes.
 
-    q (B, Hq, Sq, D) with Sq the (small, padded) decode query block;
+    q (B, Hkv, Sq, D) with Sq the (small, padded) decode query block, a
+    kv head's query heads already folded into it (:func:`_fold_heads`);
     k (B, Hkv, Sk, D); v (B, Hkv, Sk, Dv); Sk % block_k == 0.
     ``kv_length`` (B,) int32 live-prefix cursors. ``k_scale``/``v_scale``
     (B, Hkv, Sk) float32 mark the cache as int8-quantized. Returns
-    (B, Hq, Sq, Dv) in q.dtype.
+    (B, Hkv, Sq, Dv) in q.dtype. ``v=None`` with ``v_dim``: the values are
+    the first ``v_dim`` features of the key rows (a latent cache).
 
     With ``layer=i`` (static int) the cache operands are the model's
     layer-stacked, feature-major buffers — k (L, B, Hkv, D, Sk), v (L, B,
@@ -274,16 +315,22 @@ def flash_decode_fwd(q, k, v, kv_length, *,
     and ``p @ v^T`` contracts both operands' token axes.
     """
     b, hq, sq, d = q.shape
+    latent = v is None
+    kk = k if latent else v
     if layer is None:
-        _, hkv, sk, dv = v.shape
-        assert k.shape == (b, hkv, sk, d), (q.shape, k.shape, v.shape)
+        _, hkv, sk, dv = kk.shape
+        assert k.shape[:3] == (b, hkv, sk) and k.shape[3] == d, \
+            (q.shape, k.shape)
     else:
-        nl, _, hkv, dv, sk = v.shape
-        assert k.shape == (nl, b, hkv, d, sk), (q.shape, k.shape, v.shape)
+        nl, _, hkv, dv, sk = kk.shape
+        assert k.shape == (nl, b, hkv, d, sk), (q.shape, k.shape)
         assert 0 <= layer < nl, (layer, nl)
-    assert hq % hkv == 0, (hq, hkv)
+    if latent:
+        assert v_dim is not None and v_dim <= d and v_scale is None
+        dv = v_dim
+    assert hq == hkv, ("fold grouped query heads first (_fold_heads)",
+                       hq, hkv)
     assert sk % block_k == 0, (sk, block_k)
-    group = hq // hkv
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     nk = sk // block_k
@@ -319,7 +366,7 @@ def flash_decode_fwd(q, k, v, kv_length, *,
     # legal block unless Hkv == 1, (Hkv, block_k) always is.
     if layer is None:
         def kv_map(b_, h, s, ik, kvl_ref):
-            return (b_, h // group, _clamped(s * bps + ik, kvl_ref[b_]), 0)
+            return (b_, h, _clamped(s * bps + ik, kvl_ref[b_]), 0)
 
         def kvec_map(b_, h, s, ik, kvl_ref):
             return (b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
@@ -329,8 +376,7 @@ def flash_decode_fwd(q, k, v, kv_length, *,
         kdv_block = (1, 1, block_k, dv)
     else:
         def kv_map(b_, h, s, ik, kvl_ref):
-            return (layer, b_, h // group, 0,
-                    _clamped(s * bps + ik, kvl_ref[b_]))
+            return (layer, b_, h, 0, _clamped(s * bps + ik, kvl_ref[b_]))
 
         def kvec_map(b_, h, s, ik, kvl_ref):
             return (layer, b_, 0, _clamped(s * bps + ik, kvl_ref[b_]))
@@ -351,9 +397,11 @@ def flash_decode_fwd(q, k, v, kv_length, *,
         pl.BlockSpec((1, 1, sq, d),
                      lambda b_, h, s, ik, kvl_ref: (b_, h, 0, 0)),
         pl.BlockSpec(kd_block, kv_map),
-        pl.BlockSpec(kdv_block, kv_map),
     ]
-    inputs = [q_segment_ids, k_segment_ids, q_times, k_times, q, k, v]
+    inputs = [q_segment_ids, k_segment_ids, q_times, k_times, q, k]
+    if not latent:
+        in_specs.append(pl.BlockSpec(kdv_block, kv_map))
+        inputs.append(v)
     if quant_k:
         in_specs.append(pl.BlockSpec(kv_block, kvec_map))
         inputs.append(k_scale.astype(jnp.float32))
@@ -363,9 +411,10 @@ def flash_decode_fwd(q, k, v, kv_length, *,
 
     kernel = functools.partial(
         _decode_kernel, scale=float(scale), block_k=block_k,
-        blocks_per_split=bps, num_k_blocks=nk, group=group,
+        blocks_per_split=bps, num_k_blocks=nk,
         use_segments=use_segments, use_times=use_times,
-        quant_k=quant_k, quant_v=quant_v, layered=layer is not None)
+        quant_k=quant_k, quant_v=quant_v, layered=layer is not None,
+        v_in_k=dv if latent else None)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -397,7 +446,7 @@ def flash_decode_fwd(q, k, v, kv_length, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name="flash_decode",
+        name="flash_decode_latent" if latent else "flash_decode",
     )(kvl, *inputs)
     return _combine_splits(o_p, m_p, l_p, q.dtype)
 
@@ -434,35 +483,45 @@ def flash_decode(q, k, v, kv_length, *,
                  block_k: int = 128,
                  num_splits: Optional[int] = None,
                  interpret: bool = False,
-                 layer: Optional[int] = None):
+                 layer: Optional[int] = None,
+                 v_dim: Optional[int] = None):
     """Split-K ragged flash decode over arbitrary (unaligned) shapes.
 
-    Pads head dims to 128 lanes, the query length to a 16-sublane tile,
-    and the key length to ``block_k``; slices the padding back off. Key
-    rows introduced by padding sit at positions >= ``kv_length`` and are
+    Folds grouped query heads into rows (:func:`_fold_heads`), pads head
+    dims to 128 lanes, the query rows to a 16-sublane tile, and the key
+    length to ``block_k``; slices the padding back off. Key rows
+    introduced by padding sit at positions >= ``kv_length`` and are
     already unreachable through the ragged bound — no extra masking
     operand is needed. Inference-only (no custom_vjp): the decode path
-    never differentiates.
+    never differentiates. ``v=None`` with ``v_dim``: values are the first
+    ``v_dim`` features of the key rows (see :func:`flash_decode_fwd`).
 
     With ``layer`` set (layer-stacked, feature-major (L, B, H, c, S)
     cache operands), the cache is consumed **in place** and must already
     be token-aligned: ``S % block_k == 0`` (or ``S <= block_k``, which
     shrinks the block) — padding it here would copy the whole
     preallocated buffer every call. ``RolloutEngine`` and ``SimServer``
-    round ``max_len`` up to a 128 multiple for exactly this reason.
+    round ``max_len`` up to a 128 multiple, and the latent-cache
+    ``Server`` to a 512 multiple, for exactly this reason.
     """
     b, hq, sq, d = q.shape
+    kk = k if v is None else v
     if layer is None:
-        sk, dv = v.shape[-2], v.shape[-1]
+        hkv, sk, dv = kk.shape[1], kk.shape[-2], kk.shape[-1]
     else:
-        dv, sk = v.shape[-2], v.shape[-1]
+        hkv, dv, sk = kk.shape[2], kk.shape[-2], kk.shape[-1]
+    if v is None:
+        dv = v_dim
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
+    q, q_segment_ids, q_times = _fold_heads(q, hkv, q_segment_ids, q_times)
+    rows = q.shape[2]
     q = _pad_axis(q, 16, 2)
     if layer is None:
         q = _pad_axis(q, 128, 3)
         k = _pad_axis(_pad_axis(k, 128, 3), block_k, 2)
-        v = _pad_axis(_pad_axis(v, 128, 3), block_k, 2)
+        if v is not None:
+            v = _pad_axis(_pad_axis(v, 128, 3), block_k, 2)
         if k_scale is not None:
             k_scale = _pad_axis(k_scale, block_k, 2)   # (B, Hkv, Sk)
         if v_scale is not None:
@@ -478,7 +537,7 @@ def flash_decode(q, k, v, kv_length, *,
         # Feature dims are consumed as allocated (padding would copy the
         # cache). They sit on the tiles' sublane axis, with the
         # 128-aligned tokens in the lanes, so a width that is a multiple
-        # of 8 (200, 24) tiles with no padding at all.
+        # of 8 (200, 24, 576) tiles with no padding at all.
     if q_segment_ids is not None:
         q_segment_ids = _pad_axis(q_segment_ids, 16, 1, value=0)
         k_segment_ids = _pad_axis(k_segment_ids, block_k, 1, value=-1)
@@ -489,8 +548,9 @@ def flash_decode(q, k, v, kv_length, *,
         q, k, v, kv_length, k_scale=k_scale, v_scale=v_scale,
         q_segment_ids=q_segment_ids, k_segment_ids=k_segment_ids,
         q_times=q_times, k_times=k_times, scale=scale, block_k=block_k,
-        num_splits=num_splits, interpret=interpret, layer=layer)
-    return out[:, :, :sq, :dv]
+        num_splits=num_splits, interpret=interpret, layer=layer,
+        v_dim=v_dim)
+    return out[:, :, :rows, :dv].reshape(b, hq, sq, dv)    # unfold heads
 
 
 # ---------------------------------------------------------------------------
@@ -503,7 +563,8 @@ def decode_ragged_xla(q, k, v, kv_length, *,
                       q_times=None, k_times=None,
                       scale: Optional[float] = None,
                       block_k: int = 128,
-                      layer: Optional[int] = None):
+                      layer: Optional[int] = None,
+                      v_dim: Optional[int] = None):
     """Cursor-bounded online-softmax decode in pure XLA.
 
     A ``fori_loop`` whose trip count is the **batch-max** live block
@@ -532,14 +593,18 @@ def decode_ragged_xla(q, k, v, kv_length, *,
 
     Quantized caches are dequantized one block at a time inside the
     loop, so the float32 working set stays O(block), mirroring the
-    kernel's per-tile VMEM dequant.
+    kernel's per-tile VMEM dequant. ``v=None`` with ``v_dim``: the values
+    are the first ``v_dim`` features of each key block.
     """
     b, hq, sq, d = q.shape
     feat_major = layer is not None       # stacked cache: tokens last
+    kk = k if v is None else v
     if feat_major:
-        _, _, hkv, dv, sk = v.shape
+        _, _, hkv, dv, sk = kk.shape
     else:
-        _, hkv, sk, dv = v.shape
+        _, hkv, sk, dv = kk.shape
+    if v is None:
+        dv = v_dim
     group = hq // hkv
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
@@ -579,9 +644,12 @@ def decode_ragged_xla(q, k, v, kv_length, *,
         start_u = i * block_k                       # nominal block start
         start = jnp.minimum(start_u, sk - block_k)  # clamped (last block)
         kc = block_slice(k, start, block_k, tok).astype(jnp.float32)
-        vc = block_slice(v, start, block_k, tok).astype(jnp.float32)
         if k_scale is not None:
             kc = kc * row(block_slice(k_scale, start, block_k, 1))
+        if v is None:
+            vc = kc[..., :dv, :] if feat_major else kc[..., :dv]
+        else:
+            vc = block_slice(v, start, block_k, tok).astype(jnp.float32)
         if v_scale is not None:
             vc = vc * row(block_slice(v_scale, start, block_k, 1))
         if group > 1:

@@ -368,7 +368,8 @@ def decode_attention(q, k, v, *, kv_length, impl: str = "auto",
                      block_k: int = 128,
                      num_splits: Optional[int] = None,
                      interpret: Optional[bool] = None,
-                     layer: Optional[int] = None):
+                     layer: Optional[int] = None,
+                     v_dim: Optional[int] = None):
     """Attention for the incremental-decode shape: a handful of query
     tokens against a preallocated (and possibly quantized) KV cache whose
     live prefix is bounded by per-row ``kv_length`` cursors.
@@ -396,8 +397,10 @@ def decode_attention(q, k, v, *, kv_length, impl: str = "auto",
     which the ragged paths index in place — the per-layer slice is never
     materialized (the generic fallbacks *do* materialize it, and swap it
     back to ``(B, Hkv, Sk, c)``; they are O(max_len) oracles either
-    way). Masking semantics (block-causal ``q_times``/``k_times``,
-    segment ids, GQA) match :func:`attention` with ``causal=True``;
+    way). ``v=None`` with ``v_dim`` marks a latent cache whose values are
+    the first ``v_dim`` features of the key rows (MLA's absorbed decode).
+    Masking semantics (block-causal ``q_times``/``k_times``, segment ids,
+    grouped query heads) match :func:`attention` with ``causal=True``;
     decode is inference-only, so none of these paths define a VJP.
     """
     if impl == "auto":
@@ -410,14 +413,16 @@ def decode_attention(q, k, v, *, kv_length, impl: str = "auto",
             q_segment_ids=q_segment_ids, k_segment_ids=k_segment_ids,
             q_times=q_times, k_times=k_times, scale=scale,
             block_k=block_k, num_splits=num_splits, interpret=interpret,
-            layer=layer)
+            layer=layer, v_dim=v_dim)
     if impl == "xla":
         return fd.decode_ragged_xla(
             q, k, v, kv_length, k_scale=k_scale, v_scale=v_scale,
             q_segment_ids=q_segment_ids, k_segment_ids=k_segment_ids,
             q_times=q_times, k_times=k_times, scale=scale, block_k=block_k,
-            layer=layer)
+            layer=layer, v_dim=v_dim)
     if impl in ("ref", "chunked", "flash"):
+        if v is None:
+            v = k[..., :v_dim, :] if layer is not None else k[..., :v_dim]
         if layer is not None:
             k = jnp.swapaxes(k[layer], -1, -2)
             v = jnp.swapaxes(v[layer], -1, -2)

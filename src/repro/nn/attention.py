@@ -11,14 +11,18 @@ Two families:
     partial-rotary (stablelm), and a decode KV cache.
 
   * :class:`MLAttention` — DeepSeek-style Multi-head Latent Attention:
-    compressed KV latent + decoupled RoPE key. The decode path uses the
-    *absorbed* formulation (queries projected into latent space), so the KV
-    cache stays at ``kv_lora + rope_dim`` per token — the feature that makes
-    deepseek-v2/kimi-k2 long-context serving cheap.
+    compressed KV latent + decoupled RoPE key, so the cache holds one
+    ``kv_lora + rope_dim`` row per token (576 for deepseek-v2). Its cache
+    is the model's layer-stacked, feature-major latent buffer ``(L, B, 1,
+    kv_lora + rope_dim, Smax)``, written in place; single-token decode uses
+    the *absorbed* formulation (queries projected into latent space, the
+    ragged ``flash_decode`` over the latent rows, values their first
+    ``kv_lora`` features), a multi-token chunk the *expanded* one (the
+    slot's latent rows projected to per-head keys and values).
 
-Shapes: activations ``(B, S, d_model)``; caches ``(B, Hkv, Smax, D)`` plus an
-integer cursor handled by the caller (all cache slots are preallocated so
-serve steps are shape-stable under jit).
+Shapes: activations ``(B, S, d_model)``; :class:`Attention` caches ``(B,
+Hkv, Smax, D)`` plus an integer cursor handled by the caller (all cache
+slots are preallocated so serve steps are shape-stable under jit).
 """
 from __future__ import annotations
 
@@ -223,9 +227,45 @@ class Attention:
         return cache
 
 
+#: key block of the absorbed latent decode: tokens one grid step reads (a
+#: latent cache's length is a multiple of it, or shorter)
+LATENT_DECODE_BLOCK = 512
+
+
+def write_latent_rows(buf, layer, rows, slots, cursor, kv_length):
+    """Write new latent rows into the stacked cache in place.
+
+    buf (L, B, 1, c, Smax), tokens last; rows (B', n, c); ``slots`` (B',)
+    the slot of each row of ``rows`` (None: row b is slot b); cursor (B',)
+    where the n rows land; layer a static int. A row whose ``kv_length``
+    does not pass its cursor (an inactive slot) writes back what was
+    there. One ``dynamic_update_slice`` a row touches only its n rows of
+    (layer, slot), so under jit with a donated cache the update is
+    O(B' n), never O(Smax) (the pattern of ``nn.agent_sim``).
+    """
+    n, c = rows.shape[1], rows.shape[2]
+    new = jnp.swapaxes(rows, 1, 2).astype(buf.dtype)          # (B', c, n)
+    with jax.named_scope("lm.cache_write"):
+        for bi in range(rows.shape[0]):
+            si = bi if slots is None else slots[bi]
+            at = (layer, si, 0, 0, cursor[bi])
+            old = jax.lax.dynamic_slice(buf, at, (1, 1, 1, c, n))
+            upd = jnp.where(kv_length[bi] > cursor[bi],
+                            new[bi][None, None, None], old)
+            buf = jax.lax.dynamic_update_slice(buf, upd, at)
+    return buf
+
+
 @dataclasses.dataclass(frozen=True)
 class MLAttention:
-    """Multi-head Latent Attention (deepseek-v2 family)."""
+    """Multi-head Latent Attention (deepseek-v2 family).
+
+    ``scale_mult`` multiplies the softmax scale ``qk_dim ** -0.5`` (YaRN's
+    ``mscale ** 2``); ``yarn`` is the rope's YaRN scaling (``Rope1D``).
+    The rope channels of q and k_rope pair split-half (feature i with
+    i + qk_rope_dim / 2); Hugging Face's DeepSeek-V2 de-interleaves them
+    first, a fixed permutation of those weight columns.
+    """
 
     d_model: int
     num_heads: int
@@ -237,14 +277,25 @@ class MLAttention:
     rope_base: float = 10000.0
     causal: bool = True
     impl: str = "chunked"
+    scale_mult: float = 1.0
+    yarn: Optional[tuple] = None
 
     @property
     def qk_dim(self):
         return self.qk_nope_dim + self.qk_rope_dim
 
-    def _rope(self):
-        return Rope1D(head_dim=self.qk_rope_dim, base=self.rope_base)
+    @property
+    def latent_dim(self):
+        """Width of one cached row: the latent, then the rope key."""
+        return self.kv_lora_rank + self.qk_rope_dim
 
+    @property
+    def scale(self):
+        return self.scale_mult / float(self.qk_dim) ** 0.5
+
+    def _rope(self):
+        return Rope1D(head_dim=self.qk_rope_dim, base=self.rope_base,
+                      yarn=self.yarn)
     def _projs(self):
         d, h = self.d_model, self.num_heads
         dn, dr, dv, r = (self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim,
@@ -287,55 +338,106 @@ class MLAttention:
         kr = projs["k_rope"](params["k_rope"], x)             # (B, S, dr)
         return ckv, kr
 
+    def _project(self, params, projs, x, pose):
+        """(q_nope (B,H,S,dn), roped q (B,H,S,dr), latent rows (B,S,r+dr)
+        with the roped key beside the normed latent)."""
+        rope = self._rope()
+        q = self._queries(params, projs, x)
+        qn, qr = q[..., :self.qk_nope_dim], q[..., self.qk_nope_dim:]
+        qr = rope.transform_q(qr, pose[:, None, :, :])
+        ckv, kr = self._latent(params, projs, x)
+        kr = rope.transform_k(kr[:, None], pose[:, None, :, :])[:, 0]
+        return qn, qr, jnp.concatenate([ckv, kr.astype(ckv.dtype)], -1)
+
+    def _expand(self, params, lat):
+        """Latent rows (B, S, r+dr) -> per-head keys (B, H, S, dn+dr) and
+        values (B, H, S, dv)."""
+        r = self.kv_lora_rank
+        ckv, kr = lat[..., :r], lat[..., r:]
+        wk = params["k_up"]["kernel"].astype(lat.dtype)     # (r, H, dn)
+        wv = params["v_up"]["kernel"].astype(lat.dtype)     # (r, H, dv)
+        kn = jnp.einsum("bsr,rhd->bhsd", ckv, wk)
+        v = jnp.einsum("bsr,rhd->bhsd", ckv, wv)
+        k = jnp.concatenate(
+            [kn, jnp.broadcast_to(kr[:, None], kn.shape[:3]
+                                  + (self.qk_rope_dim,))], -1)
+        return k, v
+
     def __call__(self, params, x, pose=None, *, segment_ids=None, cache=None,
                  cache_index=None, impl=None):
+        """Full-sequence attention (training, prefill without a cache).
+        Cached decoding goes through :meth:`cached` on the model's
+        stacked latent cache."""
+        assert cache is None, "MLA caches are the model's stacked buffer"
         impl = impl or self.impl
         projs = self._projs()
-        rope = self._rope()
         b, s, _ = x.shape
         if pose is None:
             pose = jnp.arange(s, dtype=jnp.float32)[None, :].repeat(b, 0)
         if pose.ndim == 2:
             pose = pose[..., None].astype(jnp.float32)
-        q = self._queries(params, projs, x)
-        qn, qr = q[..., :self.qk_nope_dim], q[..., self.qk_nope_dim:]
-        qr = rope.transform_q(qr, pose[:, None, :, :])
-        ckv, kr = self._latent(params, projs, x)
-        kr = rope.transform_k(kr[:, None], pose[:, None, :, :])  # (B,1,S,dr)
-
-        if cache is not None:
-            # Absorbed decode: score = qn W_uk . ckv + qr . kr over the cache.
-            cc = _cache_update(cache["ckv"], ckv[:, None], cache_index)
-            ckr = _cache_update(cache["kr"], kr, cache_index)
-            new_cache = {"ckv": cc, "kr": ckr}
-            wk = params["k_up"]["kernel"].astype(x.dtype)   # (r, H, dn)
-            q_lat = jnp.einsum("bhsd,rhd->bhsr", qn, wk)    # (B,H,S,r)
-            q_full = jnp.concatenate([q_lat, qr], -1)       # (B,H,S,r+dr)
-            k_full = jnp.concatenate([cc, ckr], -1)         # (B,1,Smax,r+dr)
-            scale = 1.0 / float(self.qk_dim) ** 0.5
-            o_lat = kops.attention(q_full, k_full, cc, impl="chunked",
-                                   causal=self.causal, scale=scale,
-                                   q_offset=cache_index)    # (B,H,S,r)
-            wv = params["v_up"]["kernel"].astype(x.dtype)   # (r, H, dv)
-            out = jnp.einsum("bhsr,rhd->bhsd", o_lat, wv)
-            y = projs["o"](params["o"], _merge_heads(out))
-            return y, new_cache
-
-        kn = projs["k_up"](params["k_up"], ckv).transpose(0, 2, 1, 3)
-        v = projs["v_up"](params["v_up"], ckv).transpose(0, 2, 1, 3)
-        k = jnp.concatenate(
-            [kn, jnp.broadcast_to(kr, kn.shape[:3] + (self.qk_rope_dim,))], -1)
+        qn, qr, lat = self._project(params, projs, x, pose)
+        k, v = self._expand(params, lat)
         qf = jnp.concatenate([qn, qr], -1)
         qf = logical_constraint(qf, "act_batch", "act_heads", "act_seq", None)
-        scale = 1.0 / float(self.qk_dim) ** 0.5
         out = kops.attention(qf, k, v, impl=impl, causal=self.causal,
-                             scale=scale, q_segment_ids=segment_ids,
+                             scale=self.scale, q_segment_ids=segment_ids,
                              k_segment_ids=segment_ids)
         y = projs["o"](params["o"], _merge_heads(out))
         return logical_constraint(y, "act_batch", "act_seq", "act_embed"), None
 
-    def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
-        return {
-            "ckv": jnp.zeros((batch, 1, max_len, self.kv_lora_rank), dtype),
-            "kr": jnp.zeros((batch, 1, max_len, self.qk_rope_dim), dtype),
-        }
+    def cached(self, params, x, pose, buf, layer: int, *, cursor, kv_length,
+               slots=None, impl=None):
+        """Attend ``n`` new tokens a row through the stacked latent cache.
+
+        x (B', n, d_model); pose (B', n, 1) positions; buf (L, B, 1, r+dr,
+        Smax); layer static; cursor (B',) where the new rows land;
+        kv_length (B',) live rows after them (at most cursor + n; rows
+        past it, such as a chunk's padding, stay unreachable; 0 leaves an
+        inactive slot untouched); slots (B',) the rows' slots (None: row b
+        is slot b). Returns (out (B', n, d_model), buf).
+
+        n == 1 is the absorbed decode: q_lat = q_nope . W_uk, scored with
+        the rope query against the 576-wide latent rows and averaging
+        their first 512 features, by ``kops.decode_attention`` on the
+        cache in place (O(live prefix), no per-layer copy). n > 1 (a
+        prefill chunk) is expanded: the slot's rows are projected to
+        per-head keys and values and attended causally by position.
+        """
+        projs = self._projs()
+        n = x.shape[1]
+        qn, qr, lat = self._project(params, projs, x, pose)
+        buf = write_latent_rows(buf, layer, lat, slots, cursor, kv_length)
+        if n == 1:
+            assert slots is None, "decode rows are the cache's slots"
+            with jax.named_scope("lm.mla_decode"):
+                wk = params["k_up"]["kernel"].astype(x.dtype)
+                q_lat = jnp.einsum("bhsd,rhd->bhsr", qn, wk)
+                q_full = jnp.concatenate([q_lat, qr.astype(q_lat.dtype)], -1)
+                o_lat = kops.decode_attention(
+                    q_full, buf, None, kv_length=kv_length, layer=layer,
+                    v_dim=self.kv_lora_rank, scale=self.scale,
+                    impl=impl or "auto", block_k=LATENT_DECODE_BLOCK,
+                    num_splits=1)
+                wv = params["v_up"]["kernel"].astype(x.dtype)
+                out = jnp.einsum("bhsr,rhd->bhsd", o_lat.astype(x.dtype), wv)
+        else:
+            smax = buf.shape[-1]
+            rows = []
+            for bi in range(x.shape[0]):
+                si = bi if slots is None else slots[bi]
+                rows.append(jax.lax.dynamic_slice(
+                    buf, (layer, si, 0, 0, 0),
+                    (1, 1, 1, buf.shape[3], smax))[0, 0, 0])
+            cached_lat = jnp.swapaxes(jnp.stack(rows), 1, 2).astype(x.dtype)
+            k, v = self._expand(params, cached_lat)
+            qf = jnp.concatenate([qn, qr.astype(qn.dtype)], -1)
+            q_times = cursor[:, None] + jnp.arange(n, dtype=jnp.int32)
+            k_times = jnp.broadcast_to(jnp.arange(smax, dtype=jnp.int32),
+                                       (x.shape[0], smax))
+            out = kops.attention(qf, k, v, impl=impl or self.impl,
+                                 causal=True, scale=self.scale,
+                                 q_times=q_times, k_times=k_times,
+                                 kv_length=kv_length)
+        y = projs["o"](params["o"], _merge_heads(out.astype(x.dtype)))
+        return y, buf

@@ -1,6 +1,16 @@
-"""Mixture-of-Experts with shard-local capacity dispatch + expert parallelism.
+"""Mixture-of-Experts: dropless on one device, shard-local capacity dispatch
+with expert parallelism under a mesh.
 
-Dispatch strategy (all static shapes; sort/scatter provably shard-local):
+**Dropless** (no active mesh: serving on one chip, the CPU tests): every
+token goes to all of its top-k experts. The (token, expert) assignments are
+sorted by expert and run through one grouped matmul per projection
+(``jax.lax.ragged_dot``, whose group sizes are the experts' loads), so each
+expert's weights are read once for all the tokens routed to it, whatever
+the load, and nothing is dropped.
+
+**Capacity dispatch** (an active mesh: the sharded training and dry-run
+path, whose static per-group buffers are what ``shard_map`` and GSPMD
+partition; all static shapes, sort/scatter provably shard-local):
 
   1. tokens are grouped into ``G`` dispatch groups matching the mesh's
      data-parallel shards (``G = pod x data``; 1 without a mesh);
@@ -22,7 +32,9 @@ Dispatch strategy (all static shapes; sort/scatter provably shard-local):
 
 A shared-experts branch (deepseek/kimi) runs densely. Load-balance aux loss
 follows Switch Transformer. Capacity semantics are GShard-style per
-(group, expert) — the standard "dropping" strategy.
+(group, expert) — the standard "dropping" strategy. Routing weights are the
+softmax over the top-k logits (``norm_topk_prob``, the default), or the
+softmax over all experts' logits taken at the top k (deepseek-v2).
 """
 from __future__ import annotations
 
@@ -83,6 +95,7 @@ class MoE:
     aux_weight: float = 0.01
     activation: str = "silu"
     routed_scale: float = 1.0
+    norm_topk_prob: bool = True
 
     def _shared(self) -> Optional[GatedMLP]:
         if self.num_shared == 0:
@@ -112,8 +125,12 @@ class MoE:
                   / self.num_experts)
         return max(8, cap + (-cap) % 8)
 
-    def __call__(self, params, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """x: (B, S, d). Returns (y, aux_loss)."""
+    def __call__(self, params, x, layer: Optional[int] = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """x: (B, S, d). Returns (y, aux_loss). ``layer`` (static int):
+        ``params``' expert weights are the whole layer stack (L, E, ...)
+        and this call uses layer ``layer``'s (dropless path only), so no
+        per-layer copy of them is made."""
         b, s, d = x.shape
         e, k = self.num_experts, self.top_k
         t = b * s
@@ -131,13 +148,24 @@ class MoE:
             params["router"].astype(jnp.float32))                # (G, Tg, E)
         probs = jax.nn.softmax(logits, axis=-1)
         top_logits, top_ids = jax.lax.top_k(logits, k)           # (G, Tg, k)
-        weights = jax.nn.softmax(top_logits, axis=-1) * self.routed_scale
+        if self.norm_topk_prob:
+            weights = jax.nn.softmax(top_logits, axis=-1)
+        else:
+            weights = jnp.take_along_axis(probs, top_ids, axis=-1)
+        weights = weights * self.routed_scale
 
         # ---- aux load-balance loss (Switch-style) ----
         density = jnp.zeros((e,), jnp.float32).at[top_ids.reshape(-1)].add(
             1.0) / (t * k)
         mean_prob = probs.mean(axis=(0, 1))
         aux = self.aux_weight * e * jnp.sum(density * mean_prob)
+
+        if mesh is None:
+            y = self._dropless(params, xt.reshape(t, d),
+                               top_ids.reshape(t, k), weights.reshape(t, k),
+                               layer)
+            return self._finish(params, x, xt, y), aux
+        assert layer is None, "a layer stack needs the dropless path"
 
         # ---- shard-local dispatch ----
         if mesh is not None and groups > 1:
@@ -189,9 +217,49 @@ class MoE:
             y = jax.vmap(lambda a, b_, c, dd, ee: _combine_local(
                 a, b_, c, dd, ee, cap, tg))(eo, eid_s, tok_s, w_s, pos)
         y = logical_constraint(y, "act_tokens", None, None)
+        return self._finish(params, x, xt, y), aux
 
+    def _finish(self, params, x, xt, y):
+        """Routed output y (G, Tg, d) or (T, d) float32, plus the shared
+        experts, back to x's shape and dtype."""
+        b, s, d = x.shape
         shared = self._shared()
         if shared is not None:
-            y = y + shared(params["shared"], xt).astype(jnp.float32)
+            y = y + shared(params["shared"], xt).reshape(y.shape).astype(
+                jnp.float32)
         y = y.astype(x.dtype).reshape(b, s, d)
-        return logical_constraint(y, "act_batch", "act_seq", "act_embed"), aux
+        return logical_constraint(y, "act_batch", "act_seq", "act_embed")
+
+    def _dropless(self, params, xt, top_ids, weights, layer=None):
+        """Every token through all of its top-k experts: xt (T, d), top_ids
+        and weights (T, k) -> y (T, d) float32. The T*k assignments are
+        sorted by expert and each projection is one grouped matmul over
+        the expert weights, the experts' loads being its group sizes.
+        With ``layer``, the weights are the (L, E, ...) stack seen as L*E
+        groups of which only this layer's are non-empty."""
+        t, d = xt.shape
+        k, e = self.top_k, self.num_experts
+        flat = top_ids.reshape(t * k)
+        order = jnp.argsort(flat, stable=True)
+        tok = order // k
+        sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        ws = [params[n] for n in ("gate", "up", "down")]
+        if layer is not None:
+            nl = ws[0].shape[0]
+            ws = [w.reshape((nl * e,) + w.shape[2:]) for w in ws]
+            sizes = jnp.zeros((nl * e,), jnp.int32).at[
+                layer * e:(layer + 1) * e].set(sizes)
+        xs = xt[tok]                                             # (T*k, d)
+        act = ACTIVATIONS[self.activation]
+        dt = xt.dtype
+        gate, up, down = (w.astype(dt) for w in ws)
+        with jax.named_scope("moe.experts"):
+            g = jax.lax.ragged_dot(xs, gate, sizes,
+                                   preferred_element_type=jnp.float32)
+            u = jax.lax.ragged_dot(xs, up, sizes,
+                                   preferred_element_type=jnp.float32)
+            h = (act(g) * u).astype(dt)
+            eo = jax.lax.ragged_dot(h, down, sizes,
+                                    preferred_element_type=jnp.float32)
+        w = weights.reshape(t * k)[order].astype(jnp.float32)
+        return jnp.zeros((t, d), jnp.float32).at[tok].add(eo * w[:, None])

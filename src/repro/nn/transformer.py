@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.encodings import Rope1D
+from repro.core.encodings import Rope1D, yarn_mscale
 from repro.distributed.sharding import logical_constraint
 from repro.nn.attention import Attention, MLAttention
 from repro.nn.blocks import Block
@@ -82,11 +82,24 @@ class TransformerLM:
             return None
         if cfg.attention_kind == "mla":
             m = cfg.mla
+            yarn, mult = None, 1.0
+            rs = cfg.rope_scaling
+            if rs is not None:
+                # the rope's cos/sin carry yarn_mscale(mscale) /
+                # yarn_mscale(mscale_all_dim), 1 for every registered arch
+                if yarn_mscale(rs.factor, rs.mscale) != yarn_mscale(
+                        rs.factor, rs.mscale_all_dim or rs.mscale):
+                    raise NotImplementedError("rope amplitude != 1")
+                yarn = (rs.factor, rs.original_max_position, rs.beta_fast,
+                        rs.beta_slow)
+                if rs.mscale_all_dim:
+                    mult = yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
             return MLAttention(
                 d_model=cfg.d_model, num_heads=cfg.num_q_heads,
                 kv_lora_rank=m.kv_lora_rank, qk_nope_dim=m.qk_nope_dim,
                 qk_rope_dim=m.qk_rope_dim, v_head_dim=m.v_head_dim,
-                q_lora_rank=m.q_lora_rank, rope_base=cfg.rope_base)
+                q_lora_rank=m.q_lora_rank, rope_base=cfg.rope_base,
+                scale_mult=mult, yarn=yarn)
         return Attention(
             d_model=cfg.d_model, num_q_heads=cfg.num_q_heads,
             num_kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
@@ -114,7 +127,8 @@ class TransformerLM:
                        top_k=m.top_k, expert_ff=m.expert_ff,
                        num_shared=m.num_shared,
                        capacity_factor=m.capacity_factor,
-                       aux_weight=m.aux_weight, activation=cfg.activation)
+                       aux_weight=m.aux_weight, activation=cfg.activation,
+                       norm_topk_prob=m.norm_topk_prob)
         d_ff = d_ff or cfg.d_ff
         if cfg.mlp_kind == "rwkv":
             return RWKVChannelMix(cfg.d_model, d_ff)
@@ -234,6 +248,14 @@ class TransformerLM:
         """
         cfg = self.cfg
         dtype = cfg.compute_dtype
+        if cache is not None and "latent" in cache:
+            b, s = tokens.shape
+            cursor = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32),
+                                      (b,))
+            (x,), new_cache = self.latent_forward(
+                params, cache, [dict(tokens=tokens, cursor=cursor,
+                                     kv_length=cursor + s)])
+            return self.head(params, x), jnp.zeros((), jnp.float32), new_cache
         x = self.embedding(params["embedding"], tokens, dtype=dtype)
         if prefix_embeds is not None:
             x = jnp.concatenate([prefix_embeds.astype(dtype), x], axis=1)
@@ -270,17 +292,97 @@ class TransformerLM:
         x = self.final_norm(params["final_norm"], x)
         if return_hidden:
             return x, aux, (new_cache or None)
+        return self.head(params, x), aux, (new_cache or None)
+
+    def head(self, params, x):
+        """Final-normed hidden states (..., d) -> logits (..., vocab)."""
+        cfg = self.cfg
         if cfg.tie_embeddings:
             logits = self.embedding.attend(params["embedding"], x)
         else:
             logits = self.lm_head(params["lm_head"], x)
         if cfg.final_softcap:
             logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
-        logits = logical_constraint(logits, "act_batch", "act_seq", "act_vocab")
-        return logits, aux, (new_cache or None)
+        return logical_constraint(logits, "act_batch", "act_seq", "act_vocab")
+
+    # ------------------------------------------------------------------
+    def latent_forward(self, params, cache, parts):
+        """Run token groups through the layers against the stacked latent
+        cache (MLA models); returns ([final-normed hidden (B', n, d) per
+        part], new cache).
+
+        ``parts``: dicts with ``tokens`` (B', n), ``cursor`` (B',) the
+        position of each row's first token, ``kv_length`` (B',) (see
+        ``MLAttention.cached``), and optionally ``slots`` (B',) and
+        ``scope`` (a ``jax.named_scope`` for the part's own ops). Each part
+        attends on its own; the channel mixers (dense MLP or the experts)
+        run once over every part's tokens together, so the experts'
+        weights are read once a layer for all of them.
+
+        Python loop over layers, NOT ``lax.scan``: the layer index must be
+        static so the decode kernel and the row writes address the stacked
+        cache in place; scanning the cache through xs/ys would copy the
+        whole buffer every call (``nn.agent_sim`` does the same).
+        """
+        cfg = self.cfg
+        dtype = cfg.compute_dtype
+        xs, poses = [], []
+        for p in parts:
+            with jax.named_scope(p.get("scope") or "lm"):
+                tok = p["tokens"]
+                xs.append(self.embedding(params["embedding"], tok,
+                                         dtype=dtype))
+                n = tok.shape[1]
+                poses.append((p["cursor"][:, None] + jnp.arange(
+                    n, dtype=jnp.int32)).astype(jnp.float32)[..., None])
+        buf = cache["latent"]
+        li = 0
+        for gi, (blk, count) in enumerate(self.groups):
+            assert blk.ssm is None and not blk.post_norms, blk
+            gp = params[f"group{gi}"]
+            norm = blk._norm()
+            for j in range(count):
+                lp = gp if count == 1 else jax.tree.map(lambda a: a[j], gp)
+                if count > 1 and isinstance(blk.mlp, MoE):
+                    # the experts take their whole stack and the layer
+                    # index: a sliced (E, d, f) operand would be copied
+                    lp["mlp"] = {**lp["mlp"], **{n: gp["mlp"][n] for n in
+                                                 ("gate", "up", "down")}}
+                for i, p in enumerate(parts):
+                    with jax.named_scope(p.get("scope") or "lm"):
+                        h = norm(lp["norm_mix"], xs[i])
+                        a, buf = blk.attention.cached(
+                            lp["attn"], h, poses[i], buf, li,
+                            cursor=p["cursor"], kv_length=p["kv_length"],
+                            slots=p.get("slots"), impl=self.impl)
+                        xs[i] = xs[i] + a
+                hs = [norm(lp["norm_mlp"], x).reshape(-1, cfg.d_model)
+                      for x in xs]
+                h = jnp.concatenate(hs, 0)[None]
+                if isinstance(blk.mlp, MoE):
+                    m_out, _ = blk.mlp(lp["mlp"], h,
+                                       layer=j if count > 1 else None)
+                else:
+                    m_out = blk.mlp(lp["mlp"], h)
+                m_out = m_out[0]
+                at = 0
+                for i, x in enumerate(xs):
+                    t = x.shape[0] * x.shape[1]
+                    xs[i] = x + m_out[at:at + t].reshape(x.shape)
+                    at += t
+                li += 1
+        xs = [self.final_norm(params["final_norm"], x) for x in xs]
+        return xs, {"latent": buf}
 
     # ------------------------------------------------------------------
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
+        if self.cfg.attention_kind == "mla":
+            # one stacked, feature-major buffer for every layer: (L, B, 1,
+            # kv_lora + rope_dim, max_len), the latent then the rope key
+            attn = self.groups[0][0].attention
+            return {"latent": jnp.zeros(
+                (self.cfg.num_layers, batch, 1, attn.latent_dim, max_len),
+                dtype)}
         cache = {}
         for gi, (blk, count) in enumerate(self.groups):
             if isinstance(blk, tuple):

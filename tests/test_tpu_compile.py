@@ -173,3 +173,53 @@ def test_flash_forward_backward_compiles(one_chip):
                                  qkv, qkv, qkv, row, row)
     # forward + the dq and dk/dv kernels
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_lm_tick_keeps_latent_cache_in_place(one_chip, monkeypatch):
+    """The DeepSeek-V2-Lite serving tick at the benchmark cell's shapes (6
+    layers at the published widths, 64 slots of 10,240 positions, a
+    512-token prefill chunk, bfloat16): the stacked latent cache (4.53 GB)
+    is written where it lies, the experts' weights are read from their
+    layer stack without a per-layer copy (one would be 369 MB), and
+    weights, cache and temporaries fit one chip's 16 GiB."""
+    from repro.configs import get_config
+    from repro.nn.transformer import TransformerLM
+    from repro.runtime.server import init_latent_state, latent_tick
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_len, chunk = 64, 10240, 512
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                              num_layers=6)
+    model = TransformerLM(cfg)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        nnm.abstract_params(model.specs())))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, max_len, jnp.bfloat16)))
+    state = on_chip(jax.eval_shape(lambda: init_latent_state(slots)))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def tick(params, cache, state, tok, meta, temp):
+        return latent_tick(model, params, cache, state, tok, meta, temp,
+                           with_chunk=True, seed=0, eos_id=None)
+
+    compiled = _compile_for_chip(
+        tick, params, cache, state, sds((chunk,), jnp.int32),
+        sds((6,), jnp.int32), sds((), jnp.float32),
+        kernels=("flash_decode_latent",), donate=(1, 2))
+    buf = cache["latent"]
+    dims = ",".join(map(str, buf.shape))
+    for line in compiled.as_text().splitlines():
+        name, _, rhs = line.strip().partition(" = ")
+        made = re.match(r"\w+\[([\d,]*)\]\{", rhs)
+        if made and made.group(1) == dims:
+            assert " copy(" not in rhs and "remat" not in name, line
+    mem = compiled.memory_analysis()
+    cache_bytes = buf.size * buf.dtype.itemsize
+    assert mem.temp_size_in_bytes < 0.05 * cache_bytes, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes < 16 * 2 ** 30
